@@ -1,9 +1,10 @@
 """Kernel micro-benchmarks: µs/call of every implementation of the kernel
 stack, each row tagged with the implementation (``ref`` / ``interpret`` /
 ``pallas``), the block plan the selection table chose, and its **roofline
-fraction** — ``tpu_roofline_us / us_per_call``, the fraction of the analytic
-MXU roofline the measured path achieves (the comparable number across
-backends; absolute CPU µs of a TPU kernel is not).
+fraction** — ``tpu_roofline_us / us_per_call``, the fraction of the device's
+peak FLOP rate the measured path achieves. The peak comes from
+:data:`PEAKS`, keyed by ``device_kind``; a device that is not in the table
+raises, and on a CPU run both roofline columns read "not measured".
 
 ``--backward`` adds the fused_linear training-step contractions — the
 transposed-operand ``dx = dz @ wᵀ`` / ``(dw, db) = (xᵀ @ dz, Σ dz)`` refs
@@ -37,7 +38,25 @@ from repro.kernels.fused_linear.ref import (fused_linear_bwd_dw_db_ref,
 from repro.kernels.ssd_scan.ops import ssd
 from repro.kernels.ssd_scan.ref import ssd_ref
 
-PEAK = 197e12
+# Published per-chip peaks, keyed by jax.devices()[0].device_kind.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 819 GB/s HBM bandwidth.
+PEAKS = {
+    "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
+                    "source": "Google Cloud docs, TPU v5e"},
+}
+NOT_MEASURED = "not measured"
+
+
+def _peak_flops():
+    """Peak FLOP/s of the device the bench runs on; None on a CPU."""
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    if dev.device_kind not in PEAKS:
+        raise KeyError(f"no published peak for device_kind "
+                       f"{dev.device_kind!r}; add it to PEAKS with its source")
+    return PEAKS[dev.device_kind]["flops_per_s"]
 
 # the shapes the kernel-path section benches and --autotune sweeps; the two
 # fused_linear GEMMs are deliberately non-square (the shapes where the fixed
@@ -57,10 +76,16 @@ def _bench(fn, *args, iters: int = 5):
     return (time.perf_counter() - t0) / iters * 1e6
 
 
-def _row(record: dict, name: str, us: float, roofline_us: float, *,
-         impl: str, blocks=None, flops: float = None) -> None:
-    frac = roofline_us / us if us > 0 else 0.0
-    tag = f"roofline_frac={frac:.2e};impl={impl}"
+def _row(record: dict, name: str, us: float, flops: float, *,
+         impl: str, blocks=None) -> None:
+    peak = _peak_flops()
+    if peak is None:
+        roofline_us = frac = NOT_MEASURED
+        tag = f"roofline_frac={NOT_MEASURED};impl={impl}"
+    else:
+        roofline_us = flops / peak * 1e6
+        frac = roofline_us / us if us > 0 else 0.0
+        tag = f"roofline_frac={frac:.2e};impl={impl}"
     if blocks is not None:
         tag += ";blocks=" + "x".join(str(b) for b in blocks)
     emit(name, us, tag)
@@ -91,8 +116,8 @@ def _forward(record: dict) -> None:
                                   jnp.float32) for i in range(3))
     f = jax.jit(lambda a, b_, c: attention_ref(a, b_, c, causal=True))
     flops = 4 * b * h * s * s * d / 2
-    _row(record, "kernel_flash_attention_ref", _bench(f, q, kk, v),
-         flops / PEAK * 1e6, impl="ref", flops=flops)
+    _row(record, "kernel_flash_attention_ref", _bench(f, q, kk, v), flops,
+         impl="ref")
 
     # ssd scan: B=2 S=512 n=8 p=64 ds=64
     b2, s2, n, p, ds = 2, 512, 8, 64, 64
@@ -105,22 +130,21 @@ def _forward(record: dict) -> None:
     q_chunk = 128
     flops2 = b2 * s2 * n * (2 * q_chunk * p + 4 * ds * p)
     _row(record, "kernel_ssd_scan_ref", _bench(f2, xh, dt, a_log, bs, cs),
-         flops2 / PEAK * 1e6, impl="ref", flops=flops2)
+         flops2, impl="ref")
 
     # fused linear: 1024x1024x1024
     m = 1024
     x, w, bvec = _gemm_inputs(m, m, m)
     f3 = jax.jit(lambda a, b_, c: fused_linear_ref(a, b_, c, "relu"))
     flops3 = 2 * m**3
-    _row(record, "kernel_fused_linear_ref", _bench(f3, x, w, bvec),
-         flops3 / PEAK * 1e6, impl="ref", flops=flops3)
+    _row(record, "kernel_fused_linear_ref", _bench(f3, x, w, bvec), flops3,
+         impl="ref")
 
 
 def _backward(record: dict) -> None:
     k = jax.random.PRNGKey(1)
     m = 1024
     gemm_flops = 2 * m**3
-    gemm_roof = gemm_flops / PEAK * 1e6
     x = jax.random.normal(k, (m, m))
     w = jax.random.normal(jax.random.fold_in(k, 1), (m, m)) / 32
     bvec = jnp.zeros((m,))
@@ -131,11 +155,11 @@ def _backward(record: dict) -> None:
     # on TPU these become the transposed-operand Pallas kernels)
     fdx = jax.jit(lambda d, w_, y_: fused_linear_bwd_dx_ref(d, w_, y_, "relu"))
     _row(record, "kernel_fused_linear_bwd_dx_ref", _bench(fdx, dy, w, y),
-         gemm_roof, impl="ref", flops=gemm_flops)
+         gemm_flops, impl="ref")
     fdw = jax.jit(lambda x_, d, y_: fused_linear_bwd_dw_db_ref(x_, d, y_,
                                                                "relu"))
     _row(record, "kernel_fused_linear_bwd_dw_db_ref", _bench(fdw, x, dy, y),
-         gemm_roof, impl="ref", flops=gemm_flops)
+         gemm_flops, impl="ref")
 
     # end-to-end training step of the op: value+grad through the custom VJP
     # (fwd GEMM + dx + dw ≈ 3 GEMMs of work)
@@ -144,7 +168,7 @@ def _backward(record: dict) -> None:
                                             impl="ref").sum(),
         argnums=(0, 1, 2)))
     _row(record, "kernel_fused_linear_grad_ref", _bench(fstep, x, w, bvec),
-         3 * gemm_roof, impl="ref", flops=3 * gemm_flops)
+         3 * gemm_flops, impl="ref")
 
 
 def _kernel_paths(record: dict) -> None:
@@ -163,8 +187,7 @@ def _kernel_paths(record: dict) -> None:
                                                        impl=impl))
         flops = 2 * m * k * n
         _row(record, f"kernel_fused_linear_{m}x{k}x{n}_{impl}",
-             _bench(fn, x, w, b, iters=3), flops / PEAK * 1e6,
-             impl=impl, blocks=blocks, flops=flops)
+             _bench(fn, x, w, b, iters=3), flops, impl=impl, blocks=blocks)
 
     b, h, s, d = ATTN_SHAPE
     kk = jax.random.PRNGKey(2)
@@ -177,8 +200,7 @@ def _kernel_paths(record: dict) -> None:
                                                 interpret=interpret))
     flops = 4 * b * h * s * s * d / 2
     _row(record, f"kernel_flash_attention_{impl}",
-         _bench(fn, q, kt, vt, iters=3), flops / PEAK * 1e6,
-         impl=impl, blocks=blocks, flops=flops)
+         _bench(fn, q, kt, vt, iters=3), flops, impl=impl, blocks=blocks)
 
     b2, s2, n, p, ds = SSD_SHAPE
     xh = jax.random.normal(kk, (b2, s2, n, p))
@@ -192,8 +214,8 @@ def _kernel_paths(record: dict) -> None:
     chunk = blocks[0]
     flops2 = b2 * s2 * n * (2 * chunk * p + 4 * ds * p)
     _row(record, f"kernel_ssd_scan_{impl}",
-         _bench(fn, xh, dt, a_log, bs, cs, iters=3), flops2 / PEAK * 1e6,
-         impl=impl, blocks=blocks, flops=flops2)
+         _bench(fn, xh, dt, a_log, bs, cs, iters=3), flops2, impl=impl,
+         blocks=blocks)
 
 
 def _autotune(record: dict) -> None:

@@ -1,14 +1,16 @@
 """Roofline report: aggregates artifacts/dryrun/*.json into the per-
 (arch x shape x mesh) table consumed by EXPERIMENTS.md §Roofline, plus a
 kernel-stack section that converts ``kernel_bench.json`` rows into roofline
-*fractions* (``tpu_roofline_us / us_per_call`` — the backend-comparable
-number; the absolute µs of a ref/interpret row is CPU trivia)."""
+*fractions* (``tpu_roofline_us / us_per_call``). Rows timed on a CPU carry
+"not measured" in both roofline columns: a CPU or interpreter time is not a
+device number."""
 from __future__ import annotations
 
 import json
 import pathlib
 
 from benchmarks.common import ARTIFACTS, emit, save_json
+from benchmarks.kernel_bench import NOT_MEASURED
 
 DRYRUN = ARTIFACTS / "dryrun"
 KERNEL_BENCH = ARTIFACTS / "benchmarks" / "kernel_bench.json"
@@ -53,19 +55,22 @@ def kernel_fractions() -> list:
         if "us_per_call" not in row and "us" not in row:
             continue
         us = float(row.get("us_per_call", row.get("us", 0.0)))
-        roof = float(row.get("tpu_roofline_us", 0.0))
-        frac = row.get("roofline_frac",
-                       roof / us if us > 0 else 0.0)
         out.append({
             "name": name,
             "impl": row.get("impl", "ref"),
             "blocks": row.get("blocks"),
             "us_per_call": us,
-            "tpu_roofline_us": roof,
-            "roofline_frac": float(frac),
+            # "not measured" unless the row came from a run on a device
+            # with a known peak (benchmarks.kernel_bench.PEAKS)
+            "tpu_roofline_us": row.get("tpu_roofline_us", NOT_MEASURED),
+            "roofline_frac": row.get("roofline_frac", NOT_MEASURED),
             "speedup_vs_default": row.get("speedup_vs_default"),
         })
     return out
+
+
+def _num(v, fmt: str) -> str:
+    return v if isinstance(v, str) else format(v, fmt)
 
 
 def kernels_markdown(rows: list) -> str:
@@ -80,8 +85,8 @@ def kernels_markdown(rows: list) -> str:
             if r.get("speedup_vs_default") else "—"
         lines.append(
             f"| {r['name']} | {r['impl']} | {blocks} | "
-            f"{r['us_per_call']:.1f} | {r['tpu_roofline_us']:.2f} | "
-            f"{r['roofline_frac']:.2e} | {sp} |")
+            f"{r['us_per_call']:.1f} | {_num(r['tpu_roofline_us'], '.2f')} | "
+            f"{_num(r['roofline_frac'], '.2e')} | {sp} |")
     return "\n".join(lines)
 
 

@@ -63,6 +63,8 @@ def main() -> None:
     if args.only and args.only not in {name for name, _, _, _ in BENCHES}:
         ap.error(f"unknown benchmark {args.only!r} (see --list)")
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     failures = []
     for name, mod_name, kwargs, desc in BENCHES:
         if args.only and args.only != name:

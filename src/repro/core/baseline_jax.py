@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.ddsra import Workload
 from repro.core.ddsra_jax import (DDSRAPlan, RoundDecisionT, _downlink_time,
@@ -194,7 +193,7 @@ class BaselinePlan:
         but ignored — fixed-resource baselines have no Lyapunov trade-off.
         """
         del v
-        with enable_x64():
+        with jax.enable_x64(True):
             states = jax.tree.map(
                 lambda a: jnp.asarray(np.asarray(a, np.float64)), states)
             queues = jnp.asarray(np.asarray(queues, np.float64))

@@ -25,9 +25,9 @@ algorithm as data-parallel XLA:
 Precision: the numpy oracle is implicitly float64, and the bisections
 resolve constraint boundaries far below float32's ~1e-7 relative grid, so
 the jitted control plane always runs in **x64** (entry points trace and
-execute under ``jax.experimental.enable_x64`` regardless of the global
-flag; the data plane stays f32).  Parity with the oracle — identical
-assignments / selected sets, Lambda and tau within 1e-6 — is pinned in
+execute under ``jax.enable_x64(True)`` regardless of the global flag; the
+data plane stays f32). Parity with the oracle — identical assignments /
+selected sets, Lambda and tau within 1e-6 — is pinned in
 ``tests/test_ddsra_jax.py``.
 
 Ragged shop floors are padded: per-gateway device vectors are (M, n_max)
@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.ddsra import (GatewaySolution, RoundDecision, Workload, _PSI,
                               _cum)
@@ -542,7 +541,7 @@ class DDSRAPlan:
             f_dev[m, :len(devs)] = net.f_dev[devs]
             valid[m, :len(devs)] = True
             dev_idx[m, :len(devs)] = devs
-        with enable_x64():
+        with jax.enable_x64(True):
             c = _Cfg(*[jnp.asarray(float(x)) for x in (
                 cfg.phi_dev, cfg.phi_gw, cfg.v_dev, cfg.v_gw, cfg.f_gw_max,
                 cfg.f_gw_min, cfg.g_dev_max, cfg.g_gw_max, cfg.p_max,
@@ -575,7 +574,7 @@ class DDSRAPlan:
                      ) -> DecisionArrays:
         """Run the jitted round on a host-drawn ChannelState; returns the
         raw :class:`DecisionArrays` pytree of device arrays (x64)."""
-        with enable_x64():
+        with jax.enable_x64(True):
             return _round_jit(self.statics, ChannelStateT.of(st),
                               self._ctx(queues, gamma_rates, v))
 
@@ -614,7 +613,7 @@ class DDSRAPlan:
         leading ``(rounds,)`` axis. One compile per (topology, rounds)
         shape; re-running with different values never retraces.
         """
-        with enable_x64():
+        with jax.enable_x64(True):
             states = jax.tree.map(
                 lambda a: jnp.asarray(np.asarray(a, np.float64)), states)
             return _decide_scan(self.statics, states,
@@ -633,7 +632,7 @@ class DDSRAPlan:
         Returns numpy (taus, selected, queues) shaped
         (seeds, len(v_values), rounds[, M]).
         """
-        with enable_x64():
+        with jax.enable_x64(True):
             states = jax.tree.map(
                 lambda a: jnp.asarray(np.asarray(a, np.float64)), states)
             q0 = np.zeros(self.n_gateways) if queues is None else queues
@@ -650,7 +649,7 @@ class DDSRAPlan:
 
         All V lanes share the same per-round channel keys (the fair-sweep
         contract), so the trade-off curve isolates V."""
-        with enable_x64():
+        with jax.enable_x64(True):
             s = self.statics
             n_dev, j_ch = self.n_devices, self.n_channels
             gamma_rates = jnp.asarray(np.asarray(gamma_rates, np.float64))

@@ -46,11 +46,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.baseline_jax import _baseline_round, _delay_chosen
-from repro.core.ddsra_jax import (RoundContextT, _round,
-                                  resolve_decision_arrays, _Statics)
+from repro.core.ddsra_jax import (RoundContextT, _round, _Statics,
+                                  resolve_decision_arrays)
 from repro.core.network import ChannelStateT
 
 # policy name -> switch branch index. Only traced-decide policies can ride
@@ -116,7 +115,7 @@ def sweep_policies(statics: _Statics, states: ChannelStateT, gamma_rates,
     """Host entry: cast to the x64 control plane, run the fused grid and
     concretize. ``states`` leaves are (S, T, ...) host stacks; returns
     numpy (taus, selected, queues) shaped (P, S, V, T[, M])."""
-    with enable_x64():
+    with jax.enable_x64(True):
         states = jax.tree.map(
             lambda a: jnp.asarray(np.asarray(a, np.float64)), states)
         q0 = np.zeros(n_gateways) if queues is None else queues

@@ -34,7 +34,7 @@ one host replay pass:
   from the decide scan. ``eval_every`` accuracy snapshots run
   ``lax.cond``-gated *inside* the scan and cross back as per-round hit
   counts. The precision contract survives inside the pipeline: the decide
-  program runs x64 (``jax.experimental.enable_x64``), the train program
+  program runs x64 (``jax.enable_x64(True)``), the train program
   f32/bf16.
 
 Why decide and train can be phase-separated at all: every fusable policy's

@@ -27,7 +27,7 @@ Numerically the sharded round equals the single-host cohort round up to
 reduction order (parity pinned at atol 1e-5 in ``tests/test_shard.py``,
 including on a forced 8-device CPU mesh via
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``). On a 1-device
-mesh — the CPU dev box default — it degrades gracefully to a plain fused
+mesh — the default on a single-device host — it runs as a plain fused
 program.
 """
 from __future__ import annotations
@@ -38,7 +38,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 
 from repro.fl import cohort as cohort_lib
 from repro.fl import sim as sim_lib
@@ -50,6 +50,13 @@ from repro.sharding import (COHORT_AXIS, REPLICATED, SLOT_SPEC,
 # and benchmarks can assert "exactly one compile across rounds".
 # "train_scan" counts traces of the whole-run fused loop (fused_sim).
 TRACE_COUNTS = {"round": 0, "stats": 0, "train_scan": 0}
+
+
+def _replicated(mesh, tree):
+    """Place ``tree`` replicated on ``mesh``. A round's outputs come back
+    typed on the mesh; committing the inputs the same way keeps every call
+    of a program on one cache entry instead of retracing on round 2."""
+    return jax.device_put(tree, NamedSharding(mesh, REPLICATED))
 
 
 def _psum(v):
@@ -120,10 +127,10 @@ def _round_program(mesh, model: SplitModel, k_iters: int, n_tiers: int,
         return new_global, gw_loss, gw_count, loss_t, boundary, gw_models
 
     tile, rep = SLOT_SPEC, REPLICATED
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(rep, tile, tile, tile, tile, tile, tile, rep),
-                   out_specs=(rep, rep, rep, tile, tile, rep),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(rep, tile, tile, tile, tile, tile, tile, rep),
+                       out_specs=(rep, rep, rep, tile, tile, rep),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -176,11 +183,11 @@ def _train_scan_program(mesh, model: SplitModel, k_iters: int, n_tiers: int,
         return params, losses, loss_hist, hits
 
     stk, rep = STACKED_SLOT_SPEC, REPLICATED
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(rep, rep, stk, stk, stk, stk, stk, rep, rep,
-                             rep, rep, rep),
-                   out_specs=(rep, rep, rep, rep),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(rep, rep, stk, stk, stk, stk, stk, rep, rep,
+                                 rep, rep, rep),
+                       out_specs=(rep, rep, rep, rep),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -247,11 +254,11 @@ def _train_scan_program_traced(mesh, model: SplitModel, k_iters: int,
         return params, losses, loss_hist, hits
 
     stk, rep = STACKED_SLOT_SPEC, REPLICATED
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(rep, rep, rep, rep, rep, rep, rep, rep, stk,
-                             stk, stk, rep, rep, rep, rep, rep),
-                   out_specs=(rep, rep, rep, rep),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(rep, rep, rep, rep, rep, rep, rep, rep, stk,
+                                 stk, stk, rep, rep, rep, rep, rep),
+                       out_specs=(rep, rep, rep, rep),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -270,10 +277,10 @@ def _stats_program(mesh, model: SplitModel, sigma_samples: int):
         return sigma, delta, lips
 
     tile, rep = SLOT_SPEC, REPLICATED
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(rep, tile, tile, tile, tile, rep),
-                   out_specs=(tile, tile, tile),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(rep, tile, tile, tile, tile, rep),
+                       out_specs=(tile, tile, tile),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -323,7 +330,8 @@ def sharded_cohort_round(mesh, model: SplitModel, params: Params, batch, l_slot,
     fn = _round_program(mesh, model, k_iters, len(sizes),
                         with_boundary, with_gateway_models, compute_dtype)
     new_global, gw_loss, gw_count, loss_t, boundary_t, gw_models = fn(
-        params, xs, ys, masks, l_t, w_t, gw_t, jnp.float32(lr))
+        _replicated(mesh, params), xs, ys, masks, l_t, w_t, gw_t,
+        jnp.float32(lr))
 
     # trim the per-tier padding back off the per-slot outputs
     dev_losses = jnp.concatenate([v[:s] for v, s in zip(loss_t, sizes)])
@@ -345,7 +353,7 @@ def sharded_cohort_stats(mesh, model: SplitModel, params: Params, batch,
     rows = -(-n_dev // n_mesh) * n_mesh
     fn = _stats_program(mesh, model, sigma_samples)
     sigma, delta, lips = fn(
-        params,
+        _replicated(mesh, params),
         jnp.asarray(_pad_rows(np.asarray(batch.x), rows)),
         jnp.asarray(_pad_rows(np.asarray(batch.y), rows)),
         jnp.asarray(_pad_rows(np.asarray(batch.mask, np.float32), rows)),
@@ -363,8 +371,9 @@ class ShardedCohortEngine(sim_lib.CohortEngine):
     round and stats programs run under ``jax.shard_map`` with device slots
     sharded, parameters replicated, and the two-tier FedAvg reduced via
     masked psums (see the module docstring). ``Scenario.mesh_shape`` picks
-    the mesh size (``None`` = every addressable device); on a single-device
-    host it falls back to a 1-device mesh with identical numerics.
+    the mesh size (``None`` = every addressable device, one on a
+    single-device host, with identical numerics; more devices than the
+    process has raises).
     """
 
     def _mesh(self, sim: "sim_lib.Simulation"):
@@ -405,10 +414,12 @@ class ShardedCohortEngine(sim_lib.CohortEngine):
         sc = sim.scenario
         if eval_mask is None:
             eval_mask = np.zeros(trained.shape[0], bool)
-        fn = _train_scan_program(self._mesh(sim), sim.plan, sc.k_iters,
-                                 len(xs), sc.dtype)
+        mesh = self._mesh(sim)
+        fn = _train_scan_program(mesh, sim.plan, sc.k_iters, len(xs),
+                                 sc.dtype)
         x_test, y_test = self._eval_arrays(sim)
-        return fn(params, jnp.asarray(np.asarray(losses0), jnp.float32),
+        return fn(_replicated(mesh, params),
+                  jnp.asarray(np.asarray(losses0), jnp.float32),
                   xs, ys, masks, ws, gws, trained, jnp.float32(sc.lr),
                   jnp.asarray(np.asarray(eval_mask, bool)),
                   x_test, y_test)
@@ -424,11 +435,13 @@ class ShardedCohortEngine(sim_lib.CohortEngine):
         x_all, y_all, pool = self._data_stacks(sim)
         batch_lens = np.minimum(
             np.asarray(sim.d_tilde, np.int32), pool).astype(np.int32)
+        mesh = self._mesh(sim)
         fn = _train_scan_program_traced(
-            self._mesh(sim), sim.plan, sc.k_iters, len(slot_devs), sc.dtype,
+            mesh, sim.plan, sc.k_iters, len(slot_devs), sc.dtype,
             tuple(layout.tier_widths))
         x_test, y_test = self._eval_arrays(sim)
-        return fn(params, jnp.asarray(np.asarray(losses0), jnp.float32),
+        return fn(_replicated(mesh, params),
+                  jnp.asarray(np.asarray(losses0), jnp.float32),
                   x_all, y_all, jnp.asarray(pool),
                   jnp.asarray(batch_lens), sim.data_key,
                   jnp.asarray(np.asarray(ts, np.int32)), slot_devs, ws, gws,

@@ -7,7 +7,10 @@ accumulator live in VMEM scratch across k steps (the canonical flash
 recurrence). Block shapes are MXU-aligned (multiples of 128 on the lane dim;
 block_q/block_k sublane). The forward also emits the per-row log-sum-exp so
 the backward kernels can rebuild the probabilities without a second online
-pass.
+pass. The per-row lse and ``delta`` travel as ``(batch*heads, S, 1)``
+columns with ``(1, block_q, 1)`` blocks: the block's last two dims then
+meet the TPU's (8, 128) tiling rule, and each kernel reads them directly as
+the ``(block_q, 1)`` column its broadcasts need.
 
 Backward follows the standard two-kernel split (dq separately from dk/dv) so
 each kernel accumulates over exactly one sequential grid axis:
@@ -90,7 +93,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(denom))[:, 0]
+        lse_ref[0] = m_scr[...] + jnp.log(denom)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -127,11 +130,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh_, qi, ki: (bh_, qi)),
+            pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s), jnp.float32),
+            jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -162,17 +165,17 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)                 # (bk, d)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)               # (bq, d)
-    lse = lse_ref[0]                                 # (bq,)
-    delta = delta_ref[0]                             # (bq,)
+    lse = lse_ref[0]                                 # (bq, 1)
+    delta = delta_ref[0]                             # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     mask = _block_mask(qi, ki, block_q=block_q, block_k=block_k,
                        causal=causal, window=window, seq_len=seq_len)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     dq_scr[...] += jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -199,20 +202,20 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)                 # (bk, d)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)               # (bq, d)
-    lse = lse_ref[0]                                 # (bq,)
-    delta = delta_ref[0]                             # (bq,)
+    lse = lse_ref[0]                                 # (bq, 1)
+    delta = delta_ref[0]                             # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     mask = _block_mask(qi, ki, block_q=block_q, block_k=block_k,
                        causal=causal, window=window, seq_len=seq_len)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)  # (bq, bk)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)       # (bq, bk)
     # dv = pᵀ @ do: contract the shared q axis (axis 0 of both operands).
     dv_scr[...] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     # dk = dsᵀ @ q: again contract axis 0 — no transposes materialized.
     dk_scr[...] += jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())),
@@ -242,11 +245,11 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
     bh = b * h
     flat = lambda a: a.reshape(bh, s, d)
     qf, kf, vf, dof = flat(q), flat(k), flat(v), flat(do)
-    lsef = lse.reshape(bh, s).astype(jnp.float32)
-    deltaf = delta.reshape(bh, s).astype(jnp.float32)
+    lsef = lse.reshape(bh, s, 1).astype(jnp.float32)
+    deltaf = delta.reshape(bh, s, 1).astype(jnp.float32)
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0))
-    row_spec = pl.BlockSpec((1, block_q), lambda bh_, i, j: (bh_, i))
+    row_spec = pl.BlockSpec((1, block_q, 1), lambda bh_, i, j: (bh_, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(
@@ -269,7 +272,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
 
     k_spec = pl.BlockSpec((1, block_k, d), lambda bh_, ki, qi: (bh_, ki, 0))
     qq_spec = pl.BlockSpec((1, block_q, d), lambda bh_, ki, qi: (bh_, qi, 0))
-    qrow_spec = pl.BlockSpec((1, block_q), lambda bh_, ki, qi: (bh_, qi))
+    qrow_spec = pl.BlockSpec((1, block_q, 1), lambda bh_, ki, qi: (bh_, qi, 0))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=d ** -0.5, block_q=block_q,

@@ -21,6 +21,11 @@ Both backward kernels take the *activation mask* inline (``mask="relu"``
 recomputes ``dz = dy * (y > 0)`` from the saved forward output per block),
 so ``dz`` is never written to HBM. Smooth activations (silu/gelu) pass a
 pre-masked ``dz`` with ``mask="none"`` (see ``ops._linear_bwd``).
+
+Inside the kernels the bias and ``db`` travel as ``(1, n)`` rows with
+``(1, bn)`` blocks: Mosaic cannot match XLA's layout of a 1-D block, and a
+2-D row meets the TPU's (8, 128) tiling rule. The public signatures keep
+``(n,)``.
 """
 from __future__ import annotations
 
@@ -102,7 +107,7 @@ def fused_linear(x: jax.Array, w: jax.Array, b: jax.Array,
 
         @pl.when(ki == pl.num_programs(2) - 1)
         def _finalize():
-            y = acc_scr[...] + b_ref[...].astype(jnp.float32)[None, :]
+            y = acc_scr[...] + b_ref[...].astype(jnp.float32)
             o_ref[...] = ACTS[activation](y).astype(o_ref.dtype)
 
     return pl.pallas_call(
@@ -111,13 +116,13 @@ def fused_linear(x: jax.Array, w: jax.Array, b: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
             pl.BlockSpec((bk, bn), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((bn,), lambda mi, ni, ki: (ni,)),
+            pl.BlockSpec((1, bn), lambda mi, ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, w, b)
+    )(x, w, b.reshape(1, n))
 
 
 def fused_linear_bwd_dx(dy: jax.Array, w: jax.Array, y: jax.Array | None = None,
@@ -228,7 +233,7 @@ def fused_linear_bwd_dw_db(x: jax.Array, dy: jax.Array,
 
         @pl.when(jnp.logical_and(ki == 0, mi == nm - 1))
         def _finalize_db():
-            db_ref[...] = db_scr[0].astype(db_ref.dtype)
+            db_ref[...] = db_scr[...].astype(db_ref.dtype)
 
     in_specs = [pl.BlockSpec((bm, bk), lambda ni, ki, mi: (mi, ki)),
                 pl.BlockSpec((bm, bn), lambda ni, ki, mi: (mi, ni))]
@@ -237,19 +242,20 @@ def fused_linear_bwd_dw_db(x: jax.Array, dy: jax.Array,
         in_specs.append(pl.BlockSpec((bm, bn), lambda ni, ki, mi: (mi, ni)))
         operands.append(y)
 
-    return pl.pallas_call(
+    dw, db = pl.pallas_call(
         kernel,
         grid=(n // bn, k // bk, m // bm),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((bk, bn), lambda ni, ki, mi: (ki, ni)),
-            pl.BlockSpec((bn,), lambda ni, ki, mi: (ni,)),
+            pl.BlockSpec((1, bn), lambda ni, ki, mi: (0, ni)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, n), x.dtype),
-            jax.ShapeDtypeStruct((n,), dy.dtype),
+            jax.ShapeDtypeStruct((1, n), dy.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32),
                         pltpu.VMEM((1, bn), jnp.float32)],
         interpret=interpret,
     )(*operands)
+    return dw, db.reshape(n)

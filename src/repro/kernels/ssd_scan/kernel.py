@@ -6,7 +6,16 @@ TPU, so the inter-chunk SSM state lives in VMEM scratch across chunk steps
 dual quadratic form runs on the MXU; the state update is a rank-Q
 outer-product accumulation.
 
-VMEM working set per step: O(Q^2 * block_h + block_h * ds * p) — chosen so
+Operand layout: the wrapper hands the kernel head-major views — ``xh`` as
+(B, n, S, p), ``dt`` as (B, n, S, 1) columns and ``a_log`` as (n, 1, 1) — so
+the head block is a leading (untiled) dim of every block and only the chunk
+length sits on a tiled axis: each block's last two dims then meet the TPU's
+(8, 128) rule for any ``block_h``. Inside a step the heads are an unrolled
+loop of 2-D work; the in-chunk prefix sums and the column-to-row flips of
+``dt`` are masked reductions over the (Q, Q) causal/diagonal masks, which
+lower on the TPU without transposes.
+
+VMEM working set per step: O(Q^2 + block_h * (Q * p + ds * p)) — chosen so
 Q=chunk=128..256, block_h<=8 fits comfortably in 16 MB VMEM.
 """
 from __future__ import annotations
@@ -27,51 +36,50 @@ def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, h_scr,
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0].astype(jnp.float32)          # (Q, bh, p)
-    dt = dt_ref[0].astype(jnp.float32)        # (Q, bh)
-    a = -jnp.exp(alog_ref[...].astype(jnp.float32))   # (bh,)
     b = b_ref[0].astype(jnp.float32)          # (Q, ds)
     c = c_ref[0].astype(jnp.float32)          # (Q, ds)
-
-    adt = dt * a[None, :]                     # (Q, bh) log-decays
-    cum = jnp.cumsum(adt, axis=0)             # inclusive
-
-    # --- intra-chunk dual form ------------------------------------------
+    # scores[q, k] = c_q . b_k, shared by every head of the block
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (Q, K)
-    qpos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    causal = qpos >= kpos
-    ldec = jnp.exp(cum[:, None, :] - cum[None, :, :])  # (Q, K, bh)
-    w = scores[:, :, None] * jnp.where(causal[:, :, None], ldec, 0.0)
-    w = w * dt[None, :, :]                    # * dt_k
-    # y_intra[q,h,p] = sum_k w[q,k,h] x[k,h,p]  (batched over h)
-    y_intra = jax.lax.dot_general(
-        w.transpose(2, 0, 1), x.transpose(1, 0, 2),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).transpose(1, 0, 2)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = row >= col
+    diag = row == col
 
-    # --- inter-chunk contribution from carried state ---------------------
-    h = h_scr[...]                            # (bh, ds, p)
-    # y_inter[q,h,p] = exp(cum[q,h]) * sum_s c[q,s] h[h,s,p]
-    ch = jax.lax.dot_general(
-        jnp.broadcast_to(c[None], (h.shape[0],) + c.shape), h,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)   # (bh, Q, p)
-    y_inter = ch.transpose(1, 0, 2) * jnp.exp(cum)[:, :, None]
+    def to_row(v_col):                        # (Q, 1) -> (1, Q)
+        return jnp.sum(jnp.where(diag, v_col, 0.0), axis=0, keepdims=True)
 
-    y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
+    for j in range(x_ref.shape[1]):
+        x = x_ref[0, j].astype(jnp.float32)   # (Q, p)
+        dt_col = dt_ref[0, j].astype(jnp.float32)            # (Q, 1)
+        a = -jnp.exp(alog_ref[j].astype(jnp.float32))       # (1, 1)
+        adt_col = dt_col * a                  # log-decays
+        adt_row = to_row(adt_col)
+        # inclusive prefix sums, in both orientations
+        cum_col = jnp.sum(jnp.where(causal, adt_row, 0.0), axis=1,
+                          keepdims=True)                      # (Q, 1)
+        cum_row = jnp.sum(jnp.where(row <= col, adt_col, 0.0), axis=0,
+                          keepdims=True)                      # (1, Q)
 
-    # --- state update -----------------------------------------------------
-    wk = jnp.exp(cum[-1:, :] - cum) * dt      # (Q, bh)
-    # S[h,s,p] = sum_k b[k,s] wk[k,h] x[k,h,p]
-    xw = x * wk[:, :, None]                   # (Q, bh, p)
-    s_new = jax.lax.dot_general(
-        jnp.broadcast_to(b.T[None], (x.shape[1],) + (b.shape[1], b.shape[0])),
-        xw.transpose(1, 0, 2),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)   # (bh, ds, p)
-    h_scr[...] = h * jnp.exp(cum[-1])[:, None, None] + s_new
+        # --- intra-chunk dual form ---------------------------------------
+        ldec = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)
+        w = scores * ldec * to_row(dt_col)    # * dt_k
+        y_intra = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+
+        # --- inter-chunk contribution from carried state -----------------
+        h = h_scr[j]                          # (ds, p)
+        y_inter = jax.lax.dot_general(
+            c, h, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.exp(cum_col)
+        y_ref[0, j] = (y_intra + y_inter).astype(y_ref.dtype)
+
+        # --- state update: S[s, p] = sum_k b[k, s] wk[k] x[k, p] ---------
+        cum_last = cum_col[chunk - 1:chunk, :]                # (1, 1)
+        wk = jnp.exp(cum_last - cum_col) * dt_col             # (Q, 1)
+        s_new = jax.lax.dot_general(b, x * wk, (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        h_scr[j] = h * jnp.exp(cum_last) + s_new
 
 
 def ssd_scan(xh: jax.Array, dt: jax.Array, a_log: jax.Array,
@@ -87,19 +95,23 @@ def ssd_scan(xh: jax.Array, dt: jax.Array, a_log: jax.Array,
     grid = (bsz, n // block_h, s // chunk)
 
     kern = functools.partial(_ssd_kernel, chunk=chunk)
-    return pl.pallas_call(
+    head_block = pl.BlockSpec((1, block_h, chunk, p),
+                              lambda b_, h_, c_: (b_, h_, c_, 0))
+    y = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, block_h, p), lambda b_, h_, c_: (b_, c_, h_, 0)),
-            pl.BlockSpec((1, chunk, block_h), lambda b_, h_, c_: (b_, c_, h_)),
-            pl.BlockSpec((block_h,), lambda b_, h_, c_: (h_,)),
+            head_block,
+            pl.BlockSpec((1, block_h, chunk, 1),
+                         lambda b_, h_, c_: (b_, h_, c_, 0)),
+            pl.BlockSpec((block_h, 1, 1), lambda b_, h_, c_: (h_, 0, 0)),
             pl.BlockSpec((1, chunk, ds), lambda b_, h_, c_: (b_, c_, 0)),
             pl.BlockSpec((1, chunk, ds), lambda b_, h_, c_: (b_, c_, 0)),
         ],
-        out_specs=pl.BlockSpec((1, chunk, block_h, p),
-                               lambda b_, h_, c_: (b_, c_, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, s, n, p), xh.dtype),
+        out_specs=head_block,
+        out_shape=jax.ShapeDtypeStruct((bsz, n, s, p), xh.dtype),
         scratch_shapes=[pltpu.VMEM((block_h, ds, p), jnp.float32)],
         interpret=interpret,
-    )(xh, dt, a_log, b_ssm, c_ssm)
+    )(xh.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1)[..., None],
+      a_log.reshape(n, 1, 1), b_ssm, c_ssm)
+    return y.transpose(0, 2, 1, 3)

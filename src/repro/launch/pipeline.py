@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.partition import Tier, best_partition
 
@@ -100,11 +99,11 @@ def gpipe_forward(layer_fn: Callable, params_stacked, x,
         return jnp.reshape(ys, xs.shape)
 
     spec_params = jax.tree.map(lambda _: P(pod_axis), params_stacked)
-    return shard_map(
+    return jax.shard_map(
         per_pod, mesh=mesh,
         in_specs=(spec_params, P(None)),
         out_specs=P(None),
-        check_rep=False,
+        check_vma=False,
     )(params_stacked, x)
 
 

@@ -4,9 +4,25 @@ kernels (TPU target; interpret=True runs the kernel bodies on CPU).
     with backend.use_pallas(interpret=True):
         logits = model.forward(params, batch, cfg)
 
-Model code consults :func:`attention_impl` / :func:`ssd_impl`; shapes that
-don't meet the kernels' tiling constraints fall back to jnp silently (the
-kernels are drop-in replacements validated against the same oracles).
+``repro.models.model`` (attention) and ``repro.models.ssm`` (the SSD scan)
+consult :func:`current`. Inside ``use_pallas`` these shapes still run the
+jnp reference, silently:
+
+* attention (:func:`attention_ok`): a sequence length S that is not a
+  multiple of ``min(block_q, S)`` and ``min(block_k, S)``, or a head dim
+  other than 64, 80, 128 or 256;
+* the SSD scan (:func:`ssd_ok`): an S that is not a multiple of
+  ``min(chunk, S)``, or a head count n that is not a multiple of
+  ``min(block_h, n)``; also any call that passes or returns a carried
+  state (decode).
+
+Outside ``use_pallas`` attention is always the jnp reference, and the SSD
+scan follows ``repro.kernels.ssd_scan.ops.default_impl`` (``pallas`` on a
+TPU). The FL split models route their kernels without this switch: the
+VGG fc layers in ``repro.kernels.fused_linear.ops`` (Pallas on a TPU
+whenever every GEMM dim divides its clamped block; otherwise the jnp
+reference) and ``SeqSplitModel`` attention through
+``repro.kernels.flash_attention.ops.default_impl``.
 """
 from __future__ import annotations
 

@@ -41,18 +41,21 @@ def cohort_mesh(mesh_shape: Optional[Tuple[int, ...]] = None) -> Mesh:
     """Build the 1-D ``"cohort"`` mesh for the sharded FL engine.
 
     ``mesh_shape`` is the (optionally multi-dim, flattened) device count to
-    request; ``None`` uses every addressable device. The mesh degrades
-    gracefully: asking for more devices than the process has (e.g. on a
-    single-CPU dev box) silently clamps to what is available, down to a
-    1-device mesh — the sharded engine then runs as a plain fused program
-    with mathematically identical results. Use
+    request; ``None`` uses every addressable device (one device on a
+    single-device host, where the sharded engine runs as a plain fused
+    program with mathematically identical results). Asking for more devices
+    than the process has raises ``ValueError``: a mesh that silently shrank
+    would run a four-chip configuration on one chip. Use
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to exercise a
     real multi-device CPU mesh in tests.
     """
     devices = jax.devices()
     want = len(devices) if mesh_shape is None else int(np.prod(mesh_shape))
-    n = max(1, min(want, len(devices)))
-    return Mesh(np.asarray(devices[:n]), (COHORT_AXIS,))
+    if not 1 <= want <= len(devices):
+        raise ValueError(
+            f"cohort mesh {mesh_shape} needs {want} devices; this process "
+            f"has {len(devices)} ({devices[0].platform})")
+    return Mesh(np.asarray(devices[:want]), (COHORT_AXIS,))
 
 
 __all__ = ["DEFAULT_RULES", "partition_specs", "rules_for_mesh",

@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 import jax
-from jax.experimental import enable_x64
 
 from repro.core import costmodel as cm
 from repro.core import ddsra_jax
@@ -172,7 +171,7 @@ def test_hungarian_jax_matches_numpy_and_bruteforce():
     tie-breaks) and brute-force-optimal cost, on random R <= C <= 6
     matrices including ties and _PSI-masked infeasible cells."""
     rng = np.random.default_rng(0)
-    with enable_x64():
+    with jax.enable_x64(True):
         for trial in range(60):
             r = int(rng.integers(1, 7))
             c = int(rng.integers(r, 7))
@@ -193,7 +192,7 @@ def test_assign_channels_jax_parity():
     """assign_channels_jax emits the oracle's exact 0/1 incidence matrix,
     including rounds where whole gateways are _PSI-banned."""
     rng = np.random.default_rng(1)
-    with enable_x64():
+    with jax.enable_x64(True):
         for trial in range(40):
             m = int(rng.integers(2, 7))
             j = int(rng.integers(1, m + 1))

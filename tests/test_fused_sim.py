@@ -424,8 +424,7 @@ def _check_telemetry_invariants(tel: RoundTelemetry):
     # a lax.scan round-trip re-emits every leaf unchanged (the stacked
     # telemetry really is scan-shaped: leading round axis everywhere).
     # x64 on: the control-plane leaves are float64 and must survive.
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         carried = jax.lax.scan(lambda c, x: (c, x), 0, tel)[1]
     for a, b in zip(tel, carried):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -20,7 +20,6 @@ pytest.importorskip("hypothesis")  # container may lack hypothesis
 from hypothesis import given, settings, strategies as st
 
 import jax
-from jax.experimental import enable_x64
 
 from repro.core.hungarian import (assign_channels, assign_channels_jax,
                                   hungarian_min, hungarian_min_jax)
@@ -47,7 +46,7 @@ def test_hungarian_jax_triangle(r, extra, seed, kind):
     elif kind == "psi":
         cost[rng.uniform(size=cost.shape) < 0.3] = _PSI
     cols_np, total_np = hungarian_min(cost)
-    with enable_x64():
+    with jax.enable_x64(True):
         cols_jx, total_jx = _jit_hungarian(cost)
     assert np.array_equal(cols_np, np.asarray(cols_jx))
     assert float(total_jx) == pytest.approx(total_np, abs=1e-9)
@@ -68,7 +67,7 @@ def test_assign_channels_jax_property(m, j, seed, with_psi):
         theta[rng.uniform(size=theta.shape) < 0.25] = _PSI
         theta[rng.integers(m), :] = _PSI
     eye_np = assign_channels(theta)
-    with enable_x64():
+    with jax.enable_x64(True):
         eye_jx = np.asarray(assign_channels_jax(theta))
     assert np.array_equal(eye_np, eye_jx)
     assert (eye_jx.sum(axis=0) == 1).all()

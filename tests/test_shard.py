@@ -38,12 +38,15 @@ def _scenario(**kw):
 
 
 def test_cohort_mesh_clamps_to_available_devices():
-    """Asking for a bigger mesh than the host has must degrade gracefully
-    (the CPU dev box runs the sharded engine on a 1-device mesh)."""
-    mesh = cohort_mesh((4096,))
+    """Asking for a bigger mesh than the host has raises instead of
+    shrinking to the devices that exist; ``None`` takes every device."""
+    with pytest.raises(ValueError, match="needs 4096 devices"):
+        cohort_mesh((4096,))
+    with pytest.raises(ValueError, match="needs"):
+        cohort_mesh((len(jax.devices()) + 1,))
+    mesh = cohort_mesh(None)
     assert mesh.axis_names == (COHORT_AXIS,)
     assert mesh.shape[COHORT_AXIS] == len(jax.devices())
-    assert cohort_mesh(None).shape[COHORT_AXIS] == len(jax.devices())
     assert cohort_mesh((1,)).shape[COHORT_AXIS] == 1
 
 
