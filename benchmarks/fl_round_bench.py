@@ -59,10 +59,9 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import emit, save_json, timed
-from repro.core import ddsra_jax, policy_sweep
+from repro import obs
 from repro.core.network import NetworkConfig
 from repro.fl import Scenario, Simulation
-from repro.fl import cohort as cohort_lib
 
 ROUNDS, DEVICES, GATEWAYS = 10, 20, 5
 
@@ -265,9 +264,9 @@ def fused_main(fast: bool = True) -> None:
     ``tests/test_fused_sim.py`` pins them bit-identical on queues/RNG), so
     the rounds/sec ratio isolates the loop structure: per-round dispatch +
     host repackaging vs one decide scan + one train scan. Compile counts
-    are asserted in-bench via the TRACE_COUNTS deltas: a warm fused run
-    retraces nothing, and the whole seeds x V sweep grid stays one
-    executable across value changes.
+    are asserted in-bench via the ``repro.obs`` trace counters: a warm
+    fused run retraces nothing, and the whole seeds x V sweep grid stays
+    one executable across value changes.
 
     Workload: 20 devices (the paper topology's device count) spread over
     10 gateways contending for 2 channels — the channel-scarce regime DDSRA
@@ -302,10 +301,9 @@ def fused_main(fast: bool = True) -> None:
     assert all(r.trained for r in recs), "degenerate bench: idle rounds"
     sim.reset()
     sim.fused_rounds()     # warm pass traces decide + train scans
-    before = {k: d[k] for d, k in [(ddsra_jax.TRACE_COUNTS, "decide"),
-                                   (ddsra_jax.TRACE_COUNTS, "round"),
-                                   (cohort_lib.TRACE_COUNTS, "train_scan"),
-                                   (cohort_lib.TRACE_COUNTS, "round")]}
+    counted = ("trace.ddsra.decide", "trace.ddsra.round",
+               "trace.cohort.train_scan", "trace.cohort.round")
+    before = {k: obs.counters[k] for k in counted}
     step_s, fused_s = [], []
     for _ in range(reps):
         sim.reset()
@@ -317,11 +315,7 @@ def fused_main(fast: bool = True) -> None:
             sim.fused_rounds()
         fused_s.append(t_fused["s"])
     step_rps = rounds / min(step_s)
-    retraces = sum(d[k] - before[k]
-                   for d, k in [(ddsra_jax.TRACE_COUNTS, "decide"),
-                                (ddsra_jax.TRACE_COUNTS, "round"),
-                                (cohort_lib.TRACE_COUNTS, "train_scan"),
-                                (cohort_lib.TRACE_COUNTS, "round")])
+    retraces = int(sum(obs.counters[k] - before[k] for k in counted))
     fused_rps = rounds / min(fused_s)
     speedup = fused_rps / step_rps
 
@@ -339,11 +333,11 @@ def fused_main(fast: bool = True) -> None:
     seeds, v_values = [0, 1, 2], [0.01, 1.0, 100.0]
     sweep_rounds = rounds
     sim.sweep(v_values, seeds=seeds, rounds=sweep_rounds)        # warm
-    before_sweep = ddsra_jax.TRACE_COUNTS["sweep"]
+    before_sweep = obs.counters["trace.ddsra.sweep"]
     with timed() as t_sweep:
         res = sim.sweep([0.05, 5.0, 500.0], seeds=[3, 4, 5],
                         rounds=sweep_rounds)
-    sweep_retraces = ddsra_jax.TRACE_COUNTS["sweep"] - before_sweep
+    sweep_retraces = int(obs.counters["trace.ddsra.sweep"] - before_sweep)
     lanes = len(seeds) * len(v_values)
     lane_rps = lanes * sweep_rounds / t_sweep["s"]
     emit("fl_sweep_lane_rounds_per_s", lane_rps,
@@ -361,11 +355,11 @@ def fused_main(fast: bool = True) -> None:
     policies = ["ddsra_jax", "round_robin", "random", "delay_driven"]
     sim.sweep(v_values, seeds=seeds, rounds=sweep_rounds,
               policies=policies)                                 # warm
-    before_mp = policy_sweep.TRACE_COUNTS["sweep"]
+    before_mp = obs.counters["trace.policy_sweep.sweep"]
     with timed() as t_mp:
         res_mp = sim.sweep([0.05, 5.0, 500.0], seeds=[3, 4, 5],
                            rounds=sweep_rounds, policies=policies)
-    mp_retraces = policy_sweep.TRACE_COUNTS["sweep"] - before_mp
+    mp_retraces = int(obs.counters["trace.policy_sweep.sweep"] - before_mp)
     assert mp_retraces == 0, \
         "the multi-policy sweep stopped being one compiled program"
     assert res_mp.taus.shape == (len(policies), 3, 3, sweep_rounds)
@@ -424,14 +418,14 @@ def model_main(model: str, fast: bool = True) -> None:
                   net=NetworkConfig(n_gateways=4, n_devices=12, n_channels=2),
                   **MODEL_SCENARIOS[model])
     sim = Simulation(sc)
-    traces_before = cohort_lib.TRACE_COUNTS["round"]
+    traces_before = int(obs.counters["trace.cohort.round"])
     per_round, records = [], []
     it = sim.rounds("ddsra")
     for _ in range(rounds):
         with timed() as t:
             records.append(next(it))
         per_round.append(t["s"])
-    traces = cohort_lib.TRACE_COUNTS["round"] - traces_before
+    traces = int(obs.counters["trace.cohort.round"]) - traces_before
     steady = per_round[1:] if rounds > 1 else per_round
     round_ms = sum(steady) * 1e3 / len(steady)
     emit(f"fl_model_{model}_round_ms", round_ms,
@@ -468,9 +462,9 @@ def main(fast: bool = True, churn_sweep: bool = False,
 
     seq_stats_s, seq_run_s, seq_res = _simulate("sequential")
 
-    traces_before = cohort_lib.TRACE_COUNTS["round"]
+    traces_before = int(obs.counters["trace.cohort.round"])
     co_stats_s, co_run_s, co_res = _simulate("cohort")
-    traces = cohort_lib.TRACE_COUNTS["round"] - traces_before
+    traces = int(obs.counters["trace.cohort.round"]) - traces_before
 
     speedup = (seq_stats_s + seq_run_s) / (co_stats_s + co_run_s)
     run_speedup = seq_run_s / co_run_s

@@ -102,7 +102,7 @@ def max_rel_diff(a, b) -> float:
 
 
 def one_chip(jax, compile_s: dict) -> None:
-    from repro.core import ddsra_jax
+    from repro import obs
     from repro.fl import Simulation
     from repro.fl import cohort as cohort_lib
 
@@ -118,8 +118,8 @@ def one_chip(jax, compile_s: dict) -> None:
     cohort_lib.train_scan_traced = recording_train_scan
 
     def traces():
-        return (ddsra_jax.TRACE_COUNTS["decide"],
-                cohort_lib.TRACE_COUNTS["train_scan"])
+        return (obs.counters["trace.ddsra.decide"],
+                obs.counters["trace.cohort.train_scan"])
 
     t0 = time.perf_counter()
     sim = Simulation(scenario())

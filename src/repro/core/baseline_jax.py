@@ -29,14 +29,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
 from repro.core.ddsra import Workload
 from repro.core.ddsra_jax import (DDSRAPlan, RoundDecisionT, _downlink_time,
                                   _Statics, _uplink_energy, _uplink_time)
 from repro.core.lyapunov import update_queues_jax
 from repro.core.network import ChannelStateT, Network
 
-# incremented per decide-scan trace (compile-count tests read this)
-TRACE_COUNTS = {"decide": 0}
+# each decide-scan trace bumps ``repro.obs`` counter ``trace.baseline.decide``
+# (compile-count tests read it)
 
 
 def _solve_fixed(s: _Statics, st: ChannelStateT, l0: int, m, j):
@@ -128,7 +129,7 @@ def _baseline_round(s: _Statics, st: ChannelStateT, queues, gamma_rates,
 @functools.partial(jax.jit, static_argnames=("l0", "n_devices"))
 def _decide_scan(s: _Statics, states: ChannelStateT, queues, gamma_rates,
                  chosen, *, l0: int, n_devices: int) -> RoundDecisionT:
-    TRACE_COUNTS["decide"] += 1
+    obs.count("trace.baseline.decide")
 
     def step(q, xs):
         st, ch = xs
@@ -146,7 +147,7 @@ def _decide_scan_delay(s: _Statics, states: ChannelStateT, queues,
                        n_devices: int) -> RoundDecisionT:
     """Delay-driven decide trajectory: the greedy pick is computed in-scan
     from the round's channel draws instead of arriving as data."""
-    TRACE_COUNTS["decide"] += 1
+    obs.count("trace.baseline.decide")
 
     def step(q, st):
         ch = _delay_chosen(s, st, l0=l0)

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
 from repro.core.ddsra import (GatewaySolution, RoundDecision, Workload, _PSI,
                               _cum)
 from repro.core.hungarian import assign_channels_jax
@@ -58,11 +59,11 @@ _PART_ITERS = 40      # bisection trips for (21), (22), (23)/(24)
 _FREQ_ITERS = 40
 _POW_ITERS = 60
 
-# Incremented inside the traced bodies (Python side effects run only at
-# trace time): "round" per stepwise round trace, "decide" per fused
-# decide-scan trace, "sweep" per seeds x V sweep trace. Tests assert exact
-# compile counts against these (tests/conftest.py ``compile_count``).
-TRACE_COUNTS = {"round": 0, "decide": 0, "sweep": 0}
+# The traced bodies bump ``repro.obs`` counters (Python side effects run only
+# at trace time): ``trace.ddsra.round`` per stepwise round trace,
+# ``trace.ddsra.decide`` per fused decide-scan trace, ``trace.ddsra.sweep``
+# per seeds x V sweep trace. Tests assert exact compile counts against these
+# (tests/conftest.py ``compile_count``).
 
 
 class _Cfg(NamedTuple):
@@ -414,7 +415,7 @@ def _assignment(lam, queues, v):
 def _round(s: _Statics, st: ChannelStateT, ctx: RoundContextT
            ) -> DecisionArrays:
     """One whole DDSRA round as a single traced computation."""
-    TRACE_COUNTS["round"] += 1
+    obs.count("trace.ddsra.round")
     e_dev_pad = jnp.where(s.valid, st.e_dev[s.dev_idx], jnp.inf)
 
     solve = _solve_gateway
@@ -479,7 +480,7 @@ def _decide_scan(s: _Statics, states: ChannelStateT, ctx0: RoundContextT,
     vector. Returns the stacked :class:`RoundDecisionT` (leading round
     axis) plus the stacked raw :class:`DecisionArrays` queues trajectory's
     final value via the decisions themselves."""
-    TRACE_COUNTS["decide"] += 1
+    obs.count("trace.ddsra.decide")
 
     def step(queues, st):
         out = _round(s, st, ctx0._replace(queues=queues))
@@ -496,7 +497,7 @@ def _sweep_scan(s: _Statics, states: ChannelStateT, ctx0: RoundContextT,
     stacked states (leaves (S, T, ...)), ``vmap`` over V (all lanes share a
     seed's channel draws — the fair-sweep contract), ``lax.scan`` over
     rounds. Returns (taus, selected, queues) with leading (S, V, T) axes."""
-    TRACE_COUNTS["sweep"] += 1
+    obs.count("trace.ddsra.sweep")
 
     def run_v(states_1seed, v):
         def step(queues, st):
@@ -633,13 +634,17 @@ class DDSRAPlan:
         (seeds, len(v_values), rounds[, M]).
         """
         with jax.enable_x64(True):
-            states = jax.tree.map(
-                lambda a: jnp.asarray(np.asarray(a, np.float64)), states)
-            q0 = np.zeros(self.n_gateways) if queues is None else queues
-            taus, sel, qs = _sweep_scan(
-                self.statics, states, self._ctx(q0, gamma_rates, 0.0),
-                jnp.asarray(np.asarray(v_values, np.float64)))
-            return np.asarray(taus), np.asarray(sel), np.asarray(qs)
+            with obs.span("repro.sweep.dispatch"):
+                states = jax.tree.map(
+                    lambda a: jnp.asarray(np.asarray(a, np.float64)), states)
+                q0 = np.zeros(self.n_gateways) if queues is None else queues
+                taus, sel, qs = _sweep_scan(
+                    self.statics, states, self._ctx(q0, gamma_rates, 0.0),
+                    jnp.asarray(np.asarray(v_values, np.float64)))
+            with obs.span("repro.sweep.wait"):
+                taus = np.asarray(taus)
+            with obs.span("repro.sweep.fetch"):
+                return taus, np.asarray(sel), np.asarray(qs)
 
     # -- fully-fused sweeps (device-resident rounds) ---------------------
 

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
 from repro.core.baseline_jax import _baseline_round, _delay_chosen
 from repro.core.ddsra_jax import (RoundContextT, _round, _Statics,
                                   resolve_decision_arrays)
@@ -58,9 +59,9 @@ from repro.core.network import ChannelStateT
 POLICY_KINDS = {"ddsra_jax": 0, "round_robin": 1, "random": 1,
                 "delay_driven": 2}
 
-# incremented per sweep trace (compile-count tests read this): one compile
-# per (topology, P, S, V, T) shape, never per policy.
-TRACE_COUNTS = {"sweep": 0}
+# each sweep trace bumps ``repro.obs`` counter ``trace.policy_sweep.sweep``
+# (compile-count tests read it): one compile per (topology, P, S, V, T)
+# shape, never per policy.
 
 
 @functools.partial(jax.jit, static_argnames=("kinds", "l0", "n_devices"))
@@ -72,7 +73,7 @@ def _policy_sweep_scan(s: _Statics, states: ChannelStateT, queues0,
     time), ``chosen`` (P, S, T, J) gateway picks (read only by kind-1
     lanes; zeros elsewhere). Returns (taus, selected, queues) with leading
     (P, S, V, T) axes."""
-    TRACE_COUNTS["sweep"] += 1
+    obs.count("trace.policy_sweep.sweep")
 
     def policy_round(kind, q, st, ch, v):
         # every branch emits the *realized* round delay (max over trained
@@ -116,15 +117,19 @@ def sweep_policies(statics: _Statics, states: ChannelStateT, gamma_rates,
     concretize. ``states`` leaves are (S, T, ...) host stacks; returns
     numpy (taus, selected, queues) shaped (P, S, V, T[, M])."""
     with jax.enable_x64(True):
-        states = jax.tree.map(
-            lambda a: jnp.asarray(np.asarray(a, np.float64)), states)
-        q0 = np.zeros(n_gateways) if queues is None else queues
-        taus, sel, qs = _policy_sweep_scan(
-            statics, states,
-            jnp.asarray(np.asarray(q0, np.float64)),
-            jnp.asarray(np.asarray(gamma_rates, np.float64)),
-            jnp.asarray(np.asarray(chosen, np.int32)),
-            jnp.asarray(np.asarray(v_values, np.float64)),
-            kinds=tuple(int(k) for k in kinds),
-            l0=l0, n_devices=n_devices)
-        return np.asarray(taus), np.asarray(sel), np.asarray(qs)
+        with obs.span("repro.sweep.dispatch"):
+            states = jax.tree.map(
+                lambda a: jnp.asarray(np.asarray(a, np.float64)), states)
+            q0 = np.zeros(n_gateways) if queues is None else queues
+            taus, sel, qs = _policy_sweep_scan(
+                statics, states,
+                jnp.asarray(np.asarray(q0, np.float64)),
+                jnp.asarray(np.asarray(gamma_rates, np.float64)),
+                jnp.asarray(np.asarray(chosen, np.int32)),
+                jnp.asarray(np.asarray(v_values, np.float64)),
+                kinds=tuple(int(k) for k in kinds),
+                l0=l0, n_devices=n_devices)
+        with obs.span("repro.sweep.wait"):
+            taus = np.asarray(taus)
+        with obs.span("repro.sweep.fetch"):
+            return taus, np.asarray(sel), np.asarray(qs)

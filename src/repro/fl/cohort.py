@@ -50,16 +50,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.fl.data import TieredCohortBatch
 from repro.fl.data import traced_batch_indices as _traced_indices
 from repro.fl.split import flat_params as _flat
 from repro.models.split_model import Params, SplitModel
 
-# Incremented inside the traced bodies (Python side effects run only at trace
-# time), so tests/benchmarks can assert "exactly one compile across rounds".
-# "round"/"stats" count per-round program traces; "train_scan" counts traces
-# of the whole-run fused training loop (repro.fl.fused_sim).
-TRACE_COUNTS = {"round": 0, "stats": 0, "train_scan": 0}
+# The traced bodies bump ``repro.obs`` counters (Python side effects run only
+# at trace time), so tests can assert "exactly one compile across rounds":
+# ``trace.cohort.round``/``trace.cohort.stats`` count per-round program
+# traces, ``trace.cohort.train_scan`` traces of the whole-run fused training
+# loop (repro.fl.fused_sim). The named scopes ``gather``, ``local_sgd``,
+# ``fedavg`` and ``eval`` mark the train program's parts in its HLO metadata
+# (and so in a device trace), for the single-host and the sharded engine.
 
 
 def _unflatten_stacked(flat_nd: jnp.ndarray, like):
@@ -135,10 +138,6 @@ def _local_train(model: SplitModel, params: Params, xs, ys, masks,
     logits are promoted to f32 before the cross-entropy reduction.
     """
     cdt = COMPUTE_DTYPES[compute_dtype]
-    stacked = tuple(
-        jax.tree.map(lambda p: jnp.broadcast_to(p, (x.shape[0],) + p.shape),
-                     params)
-        for x in xs)
 
     def dev_step(p, xb, yb, mb):
         def loss_of(pp):
@@ -154,10 +153,17 @@ def _local_train(model: SplitModel, params: Params, xs, ys, masks,
                 for p, x, y, m in zip(p_stacks, xs, ys, masks)]
         return tuple(o[0] for o in outs), tuple(o[1] for o in outs)
 
-    final, loss_hist = jax.lax.scan(one_epoch, stacked, None, length=k_iters)
-    # last-epoch losses: matching the sequential path's "last
-    # split_sgd_step" loss semantics.
-    return final, tuple(lh[-1] for lh in loss_hist)
+    with jax.named_scope("local_sgd"):
+        stacked = tuple(
+            jax.tree.map(
+                lambda p: jnp.broadcast_to(p, (x.shape[0],) + p.shape),
+                params)
+            for x in xs)
+        final, loss_hist = jax.lax.scan(one_epoch, stacked, None,
+                                        length=k_iters)
+        # last-epoch losses: matching the sequential path's "last
+        # split_sgd_step" loss semantics.
+        return final, tuple(lh[-1] for lh in loss_hist)
 
 
 def _boundary_tiers(model: SplitModel, finals, xs, masks, ls):
@@ -207,22 +213,26 @@ def cohort_round_traced(model: SplitModel, params: Params, xs, ys, masks, l_n,
     per-round jit below, *and* the scan step of the whole-run fused
     training loop (:func:`train_scan` / ``repro.fl.fused_sim``) — one
     implementation, two compilation granularities."""
-    TRACE_COUNTS["round"] += 1
+    obs.count("trace.cohort.round")
     xs = _maybe_flatten(model, xs)
     sizes = tuple(x.shape[0] for x in xs)
     final_t, loss_t = _local_train(model, params, xs, ys, masks, k_iters, lr,
                                    compute_dtype)
-    final = _concat_tiers(final_t)
-    dev_losses = jnp.concatenate(loss_t)
+    with jax.named_scope("fedavg"):
+        final = _concat_tiers(final_t)
+        dev_losses = jnp.concatenate(loss_t)
 
-    # fused two-tier FedAvg: gateway-level then BS-level weighted averaging
-    # telescopes to one weighted average over participating devices.
-    w = weights / jnp.maximum(jnp.sum(weights), 1e-12)
-    new_global = jax.tree.map(lambda s: jnp.tensordot(w, s, axes=1), final)
+        # fused two-tier FedAvg: gateway-level then BS-level weighted
+        # averaging telescopes to one weighted average over participating
+        # devices.
+        w = weights / jnp.maximum(jnp.sum(weights), 1e-12)
+        new_global = jax.tree.map(lambda s: jnp.tensordot(w, s, axes=1),
+                                  final)
 
-    active = (weights > 0).astype(jnp.float32)
-    gw_count = gw_onehot.T @ active                                 # (M,)
-    gw_loss = (gw_onehot.T @ (dev_losses * active)) / jnp.maximum(gw_count, 1.0)
+        active = (weights > 0).astype(jnp.float32)
+        gw_count = gw_onehot.T @ active                             # (M,)
+        gw_loss = (gw_onehot.T @ (dev_losses * active)) \
+            / jnp.maximum(gw_count, 1.0)
 
     if with_boundary:
         boundary = jnp.concatenate(_boundary_tiers(
@@ -233,10 +243,12 @@ def cohort_round_traced(model: SplitModel, params: Params, xs, ys, masks, l_n,
     if with_gateway_models:
         # per-gateway shop-floor FedAvg before the global mix: columns of the
         # (N, M) incidence, weighted by d_tilde and normalized per gateway.
-        gw_w = gw_onehot * weights[:, None]
-        gw_w = gw_w / jnp.maximum(jnp.sum(gw_w, axis=0, keepdims=True), 1e-12)
-        gw_models = jax.tree.map(
-            lambda s: jnp.tensordot(gw_w.T, s, axes=1), final)   # (M, ...)
+        with jax.named_scope("fedavg"):
+            gw_w = gw_onehot * weights[:, None]
+            gw_w = gw_w / jnp.maximum(
+                jnp.sum(gw_w, axis=0, keepdims=True), 1e-12)
+            gw_models = jax.tree.map(
+                lambda s: jnp.tensordot(gw_w.T, s, axes=1), final)  # (M,...)
     else:
         gw_models = None
 
@@ -260,7 +272,40 @@ def _eval_hits(model: SplitModel, params: Params, x_eval, y_test, ev_t):
         logits = model.forward(p, x_eval)
         return jnp.sum(jnp.argmax(logits, -1) == y_test).astype(jnp.int32)
 
-    return jax.lax.cond(ev_t, hits, lambda p: jnp.int32(-1), params)
+    with jax.named_scope("eval"):
+        return jax.lax.cond(ev_t, hits, lambda p: jnp.int32(-1), params)
+
+
+def _gather_tier(x_all, y_all, pool_lens, batch_lens, data_key, t, devs,
+                 width: int):
+    """One tier's round-``t`` batches gathered in-program from the
+    device-resident shard stacks: every slot's rows by the counter-based
+    draw (``repro.fl.data.traced_batch_indices``), with its validity mask.
+    Empty slots (``dev = -1``) gather device 0's rows under an all-zero
+    mask. Shared by both engines' traced train programs."""
+    l_max = x_all.shape[1]
+
+    def one(dev):
+        d = jnp.maximum(dev, 0)
+        idx = _traced_indices(data_key, t, d, pool_lens[d], width, l_max)
+        mb = ((jnp.arange(width) < batch_lens[d]) & (dev >= 0)
+              ).astype(jnp.float32)
+        return x_all[d][idx], y_all[d][idx], mb
+
+    with jax.named_scope("gather"):
+        return jax.vmap(one)(devs)
+
+
+def _commit_round(params, new_global, losses, gw_loss, any_trained, tr_t):
+    """The scan's guards after a round's FedAvg: a round nobody trained
+    in keeps the old params (the per-round path skips the program, while
+    the normalized average would be zeros), and per-gateway losses update
+    only where the gateway trained (``sim.losses[m] = gw_loss[m]``)."""
+    with jax.named_scope("fedavg"):
+        params = jax.tree.map(
+            lambda new, old: jnp.where(any_trained, new, old),
+            new_global, params)
+        return params, jnp.where(tr_t, gw_loss, losses)
 
 
 @functools.partial(jax.jit,
@@ -296,7 +341,7 @@ def train_scan(model: SplitModel, params: Params, losses0, xs, ys, masks, ls, ws
     (T, M) f32, per-round test hits (T,) int32 — -1 where not evaluated).
     One compile per (topology, rounds) shape.
     """
-    TRACE_COUNTS["train_scan"] += 1
+    obs.count("trace.cohort.train_scan")
     x_eval = model.prepare_inputs(x_test)
 
     def step(carry, x):
@@ -307,11 +352,8 @@ def train_scan(model: SplitModel, params: Params, losses0, xs, ys, masks, ls, ws
             model, params, xs_t, ys_t, masks_t, jnp.concatenate(l_t), w,
             jnp.concatenate(gw_t), lr, k_iters=k_iters,
             with_boundary=False, compute_dtype=compute_dtype)
-        any_trained = jnp.sum(w) > 0
-        params = jax.tree.map(
-            lambda new, old: jnp.where(any_trained, new, old),
-            new_global, params)
-        losses = jnp.where(tr_t, gw_loss, losses)
+        params, losses = _commit_round(params, new_global, losses, gw_loss,
+                                       jnp.sum(w) > 0, tr_t)
         hits = _eval_hits(model, params, x_eval, y_test, ev_t)
         return (params, losses), (losses, hits)
 
@@ -349,23 +391,14 @@ def train_scan_traced(model: SplitModel, params: Params, losses0, x_all, y_all,
     Returns the same (params, losses, loss_hist, hits) as
     :func:`train_scan`.
     """
-    TRACE_COUNTS["train_scan"] += 1
+    obs.count("trace.cohort.train_scan")
     x_eval = model.prepare_inputs(x_test)
-    l_max = x_all.shape[1]
-
-    def gather_tier(t, devs, width):
-        def one(dev):
-            d = jnp.maximum(dev, 0)
-            idx = _traced_indices(data_key, t, d, pool_lens[d], width, l_max)
-            mb = ((jnp.arange(width) < batch_lens[d]) & (dev >= 0)
-                  ).astype(jnp.float32)
-            return x_all[d][idx], y_all[d][idx], mb
-        return jax.vmap(one)(devs)
 
     def step(carry, x):
         params, losses = carry
         t, sd_t, l_t, w_t, gw_t, tr_t, ev_t = x
-        gathered = [gather_tier(t, devs, width)
+        gathered = [_gather_tier(x_all, y_all, pool_lens, batch_lens,
+                                 data_key, t, devs, width)
                     for devs, width in zip(sd_t, tier_widths)]
         xs_t = tuple(g[0] for g in gathered)
         ys_t = tuple(g[1] for g in gathered)
@@ -375,11 +408,8 @@ def train_scan_traced(model: SplitModel, params: Params, losses0, x_all, y_all,
             model, params, xs_t, ys_t, masks_t, jnp.concatenate(l_t), w,
             jnp.concatenate(gw_t), lr, k_iters=k_iters,
             with_boundary=False, compute_dtype=compute_dtype)
-        any_trained = jnp.sum(w) > 0
-        params = jax.tree.map(
-            lambda new, old: jnp.where(any_trained, new, old),
-            new_global, params)
-        losses = jnp.where(tr_t, gw_loss, losses)
+        params, losses = _commit_round(params, new_global, losses, gw_loss,
+                                       jnp.sum(w) > 0, tr_t)
         hits = _eval_hits(model, params, x_eval, y_test, ev_t)
         return (params, losses), (losses, hits)
 
@@ -502,7 +532,7 @@ def _grads_sigma_lips(model: SplitModel, params: Params, x, y, mask, lr,
 @functools.partial(jax.jit, static_argnames=("model", "sigma_samples"))
 def _cohort_stats(model: SplitModel, params: Params, x, y, mask, mix_weights,
                   lr, *, sigma_samples: int):
-    TRACE_COUNTS["stats"] += 1
+    obs.count("trace.cohort.stats")
     x = model.prepare_inputs(x)
 
     grads, sigma, lips = _grads_sigma_lips(model, params, x, y, mask, lr,

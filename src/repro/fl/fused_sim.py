@@ -61,6 +61,7 @@ from typing import List, NamedTuple, Optional, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.network import ChannelState, stack_states
 from repro.core.schedulers import RoundContext, make_policy
 from repro.fl.sim import (RoundRecord, Simulation, resolve_decision)
@@ -211,7 +212,8 @@ def _decide(sim: Simulation, policy, states: List[ChannelState], t0: int):
     sc = sim.scenario
     n_dev = sim.net.cfg.n_devices
     if getattr(policy, "traced_decide", False):
-        plan = policy.plan_for(sim.workload, sim.net)
+        with obs.span("repro.fused.decide.plan", block=t0):
+            plan = policy.plan_for(sim.workload, sim.net)
         kwargs = {}
         if hasattr(policy, "traced_chosen"):
             # fixed-resource baselines: gateway picks are data — drawn /
@@ -222,13 +224,18 @@ def _decide(sim: Simulation, policy, states: List[ChannelState], t0: int):
             chosen = policy.traced_chosen(t0, len(states), sim.net)
             if chosen is not None:
                 kwargs["chosen"] = chosen
-        dec = plan.decide_scan(stack_states(states), sim.queues,
-                               sim.gamma, sc.v, **kwargs)
-        return (np.asarray(dec.selected), np.asarray(dec.trained),
-                np.asarray(dec.l_dev).astype(int),
-                np.asarray(dec.delay, np.float64),
-                np.asarray(dec.failures).astype(int),
-                np.asarray(dec.queues, np.float64))
+        with obs.span("repro.fused.decide.dispatch", block=t0):
+            dec = plan.decide_scan(stack_states(states), sim.queues,
+                                   sim.gamma, sc.v, **kwargs)
+        with obs.span("repro.fused.decide.wait", block=t0):
+            selected = np.asarray(dec.selected)
+        # the other leaves are ready: each conversion is one copy
+        with obs.span("repro.fused.decide.fetch", block=t0):
+            return (selected, np.asarray(dec.trained),
+                    np.asarray(dec.l_dev).astype(int),
+                    np.asarray(dec.delay, np.float64),
+                    np.asarray(dec.failures).astype(int),
+                    np.asarray(dec.queues, np.float64))
 
     m_gw = sim.net.cfg.n_gateways
     T = len(states)
@@ -390,9 +397,18 @@ def fused_rounds(sim: Simulation, policy, *,
     if T <= 0:
         return []
     _check_fusable(sim, policy)
+    with obs.span("repro.fused", block=t0, rounds=T):
+        return _fused_block(sim, policy, t0, T)
 
+
+def _fused_block(sim: Simulation, policy, t0: int, T: int
+                 ) -> List[RoundRecord]:
+    """The body of :func:`fused_rounds` for rounds ``t0 .. t0 + T - 1``,
+    each host step in its own ``repro.fused.*`` span."""
+    sc = sim.scenario
     # phase A: channel states from the SAME numpy stream as stepwise
-    states = [sim.net.draw() for _ in range(T)]
+    with obs.span("repro.fused.draw", block=t0):
+        states = [sim.net.draw() for _ in range(T)]
     selected, trained_mask, l_rounds, delay, failures, queues = _decide(
         sim, policy, states, t0)
 
@@ -404,54 +420,62 @@ def fused_rounds(sim: Simulation, policy, *,
     if sc.data_plane == "traced":
         # phases B+C, traced plane: pack metadata only; the scan gathers
         # every round's batches in-program via the counter-based draws
-        slot_devs, ls, ws, gws, layout = _pack_rounds_traced(
-            sim, trained_mask, l_rounds)
-        params, losses, loss_hist, hits = sim.engine.fused_train_traced(
-            sim, sim.params, sim.losses, ts, slot_devs, ls, ws, gws,
-            trained_mask, eval_mask, layout)
+        with obs.span("repro.fused.pack", block=t0):
+            slot_devs, ls, ws, gws, layout = _pack_rounds_traced(
+                sim, trained_mask, l_rounds)
+        with obs.span("repro.fused.train.dispatch", block=t0):
+            params, losses, loss_hist, hits = \
+                sim.engine.fused_train_traced(
+                    sim, sim.params, sim.losses, ts, slot_devs, ls, ws, gws,
+                    trained_mask, eval_mask, layout)
     else:
         # phase B: exact-RNG batch replay + stacking
-        xs, ys, masks, ls, ws, gws = _replay_batches(sim, trained_mask,
-                                                     l_rounds)
+        with obs.span("repro.fused.pack", block=t0):
+            xs, ys, masks, ls, ws, gws = _replay_batches(sim, trained_mask,
+                                                         l_rounds)
 
         # phase C: one training program for all rounds
-        params, losses, loss_hist, hits = sim.engine.fused_train(
-            sim, sim.params, sim.losses, xs, ys, masks, ls, ws, gws,
-            trained_mask, eval_mask)
+        with obs.span("repro.fused.train.dispatch", block=t0):
+            params, losses, loss_hist, hits = sim.engine.fused_train(
+                sim, sim.params, sim.losses, xs, ys, masks, ls, ws, gws,
+                trained_mask, eval_mask)
+    with obs.span("repro.fused.train.wait", block=t0):
+        loss_hist = np.asarray(loss_hist, np.float64)
 
-    cum = sim.delay_sum + np.cumsum(np.asarray(delay, np.float64))
-    tel = RoundTelemetry(
-        t=t0 + np.arange(T),
-        selected=np.asarray(selected, bool),
-        trained=np.asarray(trained_mask, bool),
-        l_n=np.asarray(l_rounds, int),
-        delay=np.asarray(delay, np.float64),
-        cum_delay=cum,
-        queues=np.asarray(queues, np.float64),
-        losses=np.asarray(loss_hist, np.float64),
-        failures=np.asarray(failures, int),
-        aggregations=np.asarray(trained_mask.any(axis=1), int),
-        staleness_mean=np.zeros(T), staleness_max=np.zeros(T, int),
-        stale_discarded=np.zeros(T, int), dropped_devices=np.zeros(T, int),
-        lost_devices=np.zeros(T, int), straggler_devices=np.zeros(T, int),
-        buffer_fill=np.zeros(T, int), inflight=np.zeros(T, int))
-    records = tel.to_records()
+    with obs.span("repro.fused.records", block=t0):
+        cum = sim.delay_sum + np.cumsum(np.asarray(delay, np.float64))
+        tel = RoundTelemetry(
+            t=t0 + np.arange(T),
+            selected=np.asarray(selected, bool),
+            trained=np.asarray(trained_mask, bool),
+            l_n=np.asarray(l_rounds, int),
+            delay=np.asarray(delay, np.float64),
+            cum_delay=cum,
+            queues=np.asarray(queues, np.float64),
+            losses=loss_hist,
+            failures=np.asarray(failures, int),
+            aggregations=np.asarray(trained_mask.any(axis=1), int),
+            staleness_mean=np.zeros(T), staleness_max=np.zeros(T, int),
+            stale_discarded=np.zeros(T, int), dropped_devices=np.zeros(T, int),
+            lost_devices=np.zeros(T, int), straggler_devices=np.zeros(T, int),
+            buffer_fill=np.zeros(T, int), inflight=np.zeros(T, int))
+        records = tel.to_records()
 
-    # commit the end state to the Simulation (stepwise-compatible)
-    sim.params = params
-    sim.losses = np.asarray(losses, np.float64)
-    sim.queues = np.asarray(queues[-1], np.float64).copy()
-    sim.t = t0 + T
-    sim.delay_sum = float(cum[-1])
+        # commit the end state to the Simulation (stepwise-compatible)
+        sim.params = params
+        sim.losses = np.asarray(losses, np.float64)
+        sim.queues = np.asarray(queues[-1], np.float64).copy()
+        sim.t = t0 + T
+        sim.delay_sum = float(cum[-1])
 
-    # in-scan eval: hit counts crossed the host with the telemetry; turn
-    # them into the stepwise loop's accuracy numbers (hits / test size —
-    # exact, SplitModel.accuracy's chunking does not change integer hits)
-    n_test = max(int(np.size(np.asarray(sim.ds.y_test))), 1)
-    for r, h in zip(records, np.asarray(hits)):
-        if h >= 0:
-            r.accuracy = float(int(h)) / n_test
-    return records
+        # in-scan eval: hit counts crossed the host with the telemetry; turn
+        # them into the stepwise loop's accuracy numbers (hits / test size —
+        # exact, SplitModel.accuracy's chunking does not change integer hits)
+        n_test = max(int(np.size(np.asarray(sim.ds.y_test))), 1)
+        for r, h in zip(records, np.asarray(hits)):
+            if h >= 0:
+                r.accuracy = float(int(h)) / n_test
+        return records
 
 
 # ---------------------------------------------------------------------------
@@ -499,7 +523,17 @@ def sweep(sim: Simulation, v_values, seeds=None, *,
     """
     T = sim.scenario.rounds if rounds is None else rounds
     seeds = [sim.scenario.seed] if seeds is None else [int(s) for s in seeds]
+    v_values = [float(v) for v in v_values]
+    lanes = len(seeds) * len(v_values) * (1 if policies is None
+                                          else len(policies))
+    with obs.span("repro.sweep", lanes=lanes, rounds=T):
+        return _sweep(sim, v_values, seeds, T, policies)
 
+
+def _sweep(sim: Simulation, v_values, seeds: List[int], T: int,
+           policies: Optional[List[str]]) -> SweepResult:
+    """The body of :func:`sweep`, each host step in its own
+    ``repro.sweep.*`` span (dispatch and wait are the plan's)."""
     if policies is not None:
         from repro.core import policy_sweep as ps
         from repro.core.baseline_jax import BaselinePlan
@@ -510,27 +544,30 @@ def sweep(sim: Simulation, v_values, seeds=None, *,
                 f"decide); traced-decide policies: "
                 f"{sorted(ps.POLICY_KINDS)} — use Simulation.rounds() for "
                 "the rest")
-        plan = BaselinePlan.build(sim.workload, sim.net)
-        per_seed = [stack_states(_seed_states(sim, s, T)) for s in seeds]
-        stacked = jax.tree.map(lambda *a: np.stack(a), *per_seed)
+        with obs.span("repro.sweep.plan"):
+            plan = BaselinePlan.build(sim.workload, sim.net)
+        with obs.span("repro.sweep.draw"):
+            per_seed = [stack_states(_seed_states(sim, s, T)) for s in seeds]
+            stacked = jax.tree.map(lambda *a: np.stack(a), *per_seed)
         kinds = np.array([ps.POLICY_KINDS[p] for p in policies], np.int32)
         j_ch = sim.net.cfg.n_channels
         chosen = np.zeros((len(policies), len(seeds), T, j_ch), np.int32)
-        for pi, name in enumerate(policies):
-            if ps.POLICY_KINDS[name] != 1:
-                continue
-            for si, s in enumerate(seeds):
-                # fresh per-seed policy instance == the stepwise
-                # reset(seed) contract (make_policy reseeds from run_seed)
-                pol = make_policy(name, seed=s)
-                chosen[pi, si] = pol.traced_chosen(0, T, sim.net)
+        with obs.span("repro.sweep.picks"):
+            for pi, name in enumerate(policies):
+                if ps.POLICY_KINDS[name] != 1:
+                    continue
+                for si, s in enumerate(seeds):
+                    # fresh per-seed policy instance == the stepwise
+                    # reset(seed) contract (make_policy reseeds from
+                    # run_seed)
+                    pol = make_policy(name, seed=s)
+                    chosen[pi, si] = pol.traced_chosen(0, T, sim.net)
         taus, sel, queues = ps.sweep_policies(
-            plan.statics, stacked, sim.gamma, list(map(float, v_values)),
-            kinds, chosen, l0=plan.l0, n_devices=plan.n_devices,
+            plan.statics, stacked, sim.gamma, v_values, kinds, chosen,
+            l0=plan.l0, n_devices=plan.n_devices,
             n_gateways=plan.n_gateways)
-        return SweepResult(seeds=seeds,
-                           v_values=[float(v) for v in v_values],
-                           taus=taus, selected=sel, queues=queues,
+        return SweepResult(seeds=seeds, v_values=v_values, taus=taus,
+                           selected=sel, queues=queues,
                            policies=list(policies))
 
     policy = sim._resolve_policy(None)
@@ -539,15 +576,16 @@ def sweep(sim: Simulation, v_values, seeds=None, *,
             f"Simulation.sweep() needs a traced-decide policy; scenario "
             f"policy {sim.scenario.policy!r} decides on the host — set "
             "Scenario.policy='ddsra_jax'")
-    plan = policy.plan_for(sim.workload, sim.net)
+    with obs.span("repro.sweep.plan"):
+        plan = policy.plan_for(sim.workload, sim.net)
     if not hasattr(plan, "sweep_states"):
         raise ValueError(
             f"policy {sim.scenario.policy!r} has no V-sweep (fixed-resource "
             "baselines ignore V); set Scenario.policy='ddsra_jax' or pass "
             "policies=[...] to sweep them on the policy axis")
-    per_seed = [stack_states(_seed_states(sim, s, T)) for s in seeds]
-    stacked = jax.tree.map(lambda *a: np.stack(a), *per_seed)
-    taus, sel, queues = plan.sweep_states(stacked, sim.gamma,
-                                          list(map(float, v_values)))
-    return SweepResult(seeds=seeds, v_values=[float(v) for v in v_values],
-                       taus=taus, selected=sel, queues=queues)
+    with obs.span("repro.sweep.draw"):
+        per_seed = [stack_states(_seed_states(sim, s, T)) for s in seeds]
+        stacked = jax.tree.map(lambda *a: np.stack(a), *per_seed)
+    taus, sel, queues = plan.sweep_states(stacked, sim.gamma, v_values)
+    return SweepResult(seeds=seeds, v_values=v_values, taus=taus,
+                       selected=sel, queues=queues)

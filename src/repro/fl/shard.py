@@ -40,17 +40,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
+from repro import obs
 from repro.fl import cohort as cohort_lib
 from repro.fl import sim as sim_lib
 from repro.models.split_model import Params, SplitModel
 from repro.sharding import (COHORT_AXIS, REPLICATED, SLOT_SPEC,
                             STACKED_SLOT_SPEC, cohort_mesh)
-
-# Trace-time counters (Python side effects run only while tracing), so tests
-# and benchmarks can assert "exactly one compile across rounds".
-# "train_scan" counts traces of the whole-run fused loop (fused_sim).
-TRACE_COUNTS = {"round": 0, "stats": 0, "train_scan": 0}
-
 
 def _replicated(mesh, tree):
     """Place ``tree`` replicated on ``mesh``. A round's outputs come back
@@ -68,15 +63,18 @@ def _fedavg_psum(final, w, losses, gw):
     over the cohort axis — the reduction core shared by the per-round
     sharded program and the whole-run fused loop. ``final``/``w``/
     ``losses``/``gw`` are local-shard slot-major values; returns the
-    replicated (new_global, gw_loss, gw_count, w_sum)."""
-    w_sum = _psum(jnp.sum(w))
-    new_global = jax.tree.map(
-        lambda s: _psum(jnp.tensordot(w, s, axes=1))
-        / jnp.maximum(w_sum, 1e-12), final)
-    active = (w > 0).astype(jnp.float32)
-    gw_count = _psum(gw.T @ active)                                 # (M,)
-    gw_loss = _psum(gw.T @ (losses * active)) / jnp.maximum(gw_count, 1.0)
-    return new_global, gw_loss, gw_count, w_sum
+    replicated (new_global, gw_loss, gw_count, w_sum), under the
+    ``fedavg`` named scope."""
+    with jax.named_scope("fedavg"):
+        w_sum = _psum(jnp.sum(w))
+        new_global = jax.tree.map(
+            lambda s: _psum(jnp.tensordot(w, s, axes=1))
+            / jnp.maximum(w_sum, 1e-12), final)
+        active = (w > 0).astype(jnp.float32)
+        gw_count = _psum(gw.T @ active)                             # (M,)
+        gw_loss = _psum(gw.T @ (losses * active)) \
+            / jnp.maximum(gw_count, 1.0)
+        return new_global, gw_loss, gw_count, w_sum
 
 
 @functools.lru_cache(maxsize=None)
@@ -89,7 +87,7 @@ def _round_program(mesh, model: SplitModel, k_iters: int, n_tiers: int,
     lru_cache key, so f32 and bf16 rounds compile separate programs)."""
 
     def body(params, xs, ys, masks, ls, ws, gws, lr):
-        TRACE_COUNTS["round"] += 1
+        obs.count("trace.shard.round")
         xs = cohort_lib._maybe_flatten(model, xs)
         final_t, loss_t = cohort_lib._local_train(
             model, params, xs, ys, masks, k_iters, lr, compute_dtype)
@@ -154,7 +152,7 @@ def _train_scan_program(mesh, model: SplitModel, k_iters: int, n_tiers: int,
 
     def body(params, losses0, xs, ys, masks, ws, gws, trained, lr,
              eval_mask, x_test, y_test):
-        TRACE_COUNTS["train_scan"] += 1
+        obs.count("trace.shard.train_scan")
         x_eval = model.prepare_inputs(x_test)
 
         def step(carry, x):
@@ -168,11 +166,8 @@ def _train_scan_program(mesh, model: SplitModel, k_iters: int, n_tiers: int,
             new_global, gw_loss, _, w_sum = _fedavg_psum(
                 final, jnp.concatenate(w_t), jnp.concatenate(loss_t),
                 jnp.concatenate(gw_t))
-            any_trained = w_sum > 0
-            params = jax.tree.map(
-                lambda new, old: jnp.where(any_trained, new, old),
-                new_global, params)
-            losses = jnp.where(tr_t, gw_loss, losses)
+            params, losses = cohort_lib._commit_round(
+                params, new_global, losses, gw_loss, w_sum > 0, tr_t)
             hits = cohort_lib._eval_hits(model, params, x_eval, y_test,
                                          ev_t)
             return (params, losses), (losses, hits)
@@ -209,24 +204,15 @@ def _train_scan_program_traced(mesh, model: SplitModel, k_iters: int,
     def body(params, losses0, x_all, y_all, pool_lens, batch_lens, data_key,
              ts, slot_devs, ws, gws, trained, lr, eval_mask, x_test,
              y_test):
-        TRACE_COUNTS["train_scan"] += 1
+        obs.count("trace.shard.train_scan")
         x_eval = model.prepare_inputs(x_test)
-        l_max = x_all.shape[1]
-
-        def gather_tier(t, devs, width):
-            def one(dev):
-                d = jnp.maximum(dev, 0)
-                idx = cohort_lib._traced_indices(data_key, t, d,
-                                                 pool_lens[d], width, l_max)
-                mb = ((jnp.arange(width) < batch_lens[d]) & (dev >= 0)
-                      ).astype(jnp.float32)
-                return x_all[d][idx], y_all[d][idx], mb
-            return jax.vmap(one)(devs)
 
         def step(carry, x):
             params, losses = carry
             t, sd_t, w_t, gw_t, tr_t, ev_t = x
-            gathered = [gather_tier(t, devs, width)
+            gathered = [cohort_lib._gather_tier(x_all, y_all, pool_lens,
+                                                batch_lens, data_key, t,
+                                                devs, width)
                         for devs, width in zip(sd_t, tier_widths)]
             xs_t = cohort_lib._maybe_flatten(
                 model, tuple(g[0] for g in gathered))
@@ -239,11 +225,8 @@ def _train_scan_program_traced(mesh, model: SplitModel, k_iters: int,
             new_global, gw_loss, _, w_sum = _fedavg_psum(
                 final, jnp.concatenate(w_t), jnp.concatenate(loss_t),
                 jnp.concatenate(gw_t))
-            any_trained = w_sum > 0
-            params = jax.tree.map(
-                lambda new, old: jnp.where(any_trained, new, old),
-                new_global, params)
-            losses = jnp.where(tr_t, gw_loss, losses)
+            params, losses = cohort_lib._commit_round(
+                params, new_global, losses, gw_loss, w_sum > 0, tr_t)
             hits = cohort_lib._eval_hits(model, params, x_eval, y_test,
                                          ev_t)
             return (params, losses), (losses, hits)
@@ -268,7 +251,7 @@ def _stats_program(mesh, model: SplitModel, sigma_samples: int):
     only the globally-mixed gradient (for delta_n) crosses shards."""
 
     def body(params, x, y, mask, mix_w, lr):
-        TRACE_COUNTS["stats"] += 1
+        obs.count("trace.shard.stats")
         x = model.prepare_inputs(x)
         grads, sigma, lips = cohort_lib._grads_sigma_lips(
             model, params, x, y, mask, lr, sigma_samples)
@@ -418,11 +401,12 @@ class ShardedCohortEngine(sim_lib.CohortEngine):
         fn = _train_scan_program(mesh, sim.plan, sc.k_iters, len(xs),
                                  sc.dtype)
         x_test, y_test = self._eval_arrays(sim)
-        return fn(_replicated(mesh, params),
-                  jnp.asarray(np.asarray(losses0), jnp.float32),
-                  xs, ys, masks, ws, gws, trained, jnp.float32(sc.lr),
-                  jnp.asarray(np.asarray(eval_mask, bool)),
-                  x_test, y_test)
+        return obs.call_keeping(
+            "train_scan", "trace.shard.train_scan", fn,
+            _replicated(mesh, params),
+            jnp.asarray(np.asarray(losses0), jnp.float32),
+            xs, ys, masks, ws, gws, trained, jnp.float32(sc.lr),
+            jnp.asarray(np.asarray(eval_mask, bool)), x_test, y_test)
 
     def fused_train_traced(self, sim: "sim_lib.Simulation", params, losses0,
                            ts, slot_devs, ls, ws, gws, trained, eval_mask,
@@ -440,11 +424,11 @@ class ShardedCohortEngine(sim_lib.CohortEngine):
             mesh, sim.plan, sc.k_iters, len(slot_devs), sc.dtype,
             tuple(layout.tier_widths))
         x_test, y_test = self._eval_arrays(sim)
-        return fn(_replicated(mesh, params),
-                  jnp.asarray(np.asarray(losses0), jnp.float32),
-                  x_all, y_all, jnp.asarray(pool),
-                  jnp.asarray(batch_lens), sim.data_key,
-                  jnp.asarray(np.asarray(ts, np.int32)), slot_devs, ws, gws,
-                  trained, jnp.float32(sc.lr),
-                  jnp.asarray(np.asarray(eval_mask, bool)),
-                  x_test, y_test)
+        return obs.call_keeping(
+            "train_scan", "trace.shard.train_scan", fn,
+            _replicated(mesh, params),
+            jnp.asarray(np.asarray(losses0), jnp.float32),
+            x_all, y_all, jnp.asarray(pool), jnp.asarray(batch_lens),
+            sim.data_key, jnp.asarray(np.asarray(ts, np.int32)), slot_devs,
+            ws, gws, trained, jnp.float32(sc.lr),
+            jnp.asarray(np.asarray(eval_mask, bool)), x_test, y_test)

@@ -34,7 +34,6 @@ import pathlib
 import queue
 import re
 import threading
-import time
 import warnings
 from typing import Dict, Iterator, List, Optional, Tuple, Type, Union
 
@@ -42,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import store
 from repro.core import costmodel as cm
 from repro.core.ddsra import RoundDecision, Workload
@@ -561,7 +561,8 @@ class CohortEngine(Engine):
         if eval_mask is None:
             eval_mask = np.zeros(np.asarray(trained).shape[0], bool)
         x_test, y_test = self._eval_arrays(sim)
-        return cohort_lib.train_scan(
+        return obs.call_keeping(
+            "train_scan", "trace.cohort.train_scan", cohort_lib.train_scan,
             sim.plan, params, losses0, xs, ys, masks, ls, ws, gws, trained,
             np.float32(sc.lr), np.asarray(eval_mask, bool),
             x_test, y_test,
@@ -641,7 +642,9 @@ class CohortEngine(Engine):
         batch_lens = np.minimum(
             np.asarray(sim.d_tilde, np.int32), pool).astype(np.int32)
         x_test, y_test = self._eval_arrays(sim)
-        return cohort_lib.train_scan_traced(
+        return obs.call_keeping(
+            "train_scan", "trace.cohort.train_scan",
+            cohort_lib.train_scan_traced,
             sim.plan, params, losses0, x_all, y_all, pool, batch_lens,
             sim.data_key, np.asarray(ts, np.int32), slot_devs, ls, ws, gws,
             trained, np.float32(sc.lr), np.asarray(eval_mask, bool),
@@ -846,27 +849,32 @@ class Simulation:
         # built *before* the dataset so its input_kind can pick the data
         # path (consumes only the jax PRNG — the numpy byte stream the
         # image dataset replays is untouched).
-        key = jax.random.PRNGKey(sc.seed)
-        self.plan, params, self.layers = model_registry.build_fl_model(
-            sc.model, key, sc)
-        self.bs = BaseStation(self.plan, params)
+        with obs.span("repro.setup.weights"):
+            key = jax.random.PRNGKey(sc.seed)
+            self.plan, params, self.layers = model_registry.build_fl_model(
+                sc.model, key, sc)
+            self.bs = BaseStation(self.plan, params)
 
-        if self.plan.input_kind == "tokens":
-            # token models: per-device Markov-chain corpora whose transition
-            # tables play the role of the class mixture (chi-mixed)
-            self.ds = make_token_fl_dataset(
-                ncfg.n_devices, self.d_sizes, vocab=self.plan.classes,
-                seq_len=sc.seq_len, chi=sc.chi, seed=sc.seed)
-        else:
-            # non-IID classes: gateway 0's devices see the widest variety
-            # (paper Sec. VII-B: "the 1-th gateway ... a wider variety")
-            q = np.zeros(ncfg.n_devices, dtype=int)
-            for n in range(ncfg.n_devices):
-                gw = self.net.assign[n]
-                q[n] = sc.classes if gw == 0 else int(self.rng.integers(1, 4))
-            self.ds = make_fl_dataset(ncfg.n_devices, self.d_sizes, q,
-                                      chi=sc.chi, classes=sc.classes,
-                                      seed=sc.seed)
+        with obs.span("repro.setup.data"):
+            if self.plan.input_kind == "tokens":
+                # token models: per-device Markov-chain corpora whose
+                # transition tables play the role of the class mixture
+                # (chi-mixed)
+                self.ds = make_token_fl_dataset(
+                    ncfg.n_devices, self.d_sizes, vocab=self.plan.classes,
+                    seq_len=sc.seq_len, chi=sc.chi, seed=sc.seed)
+            else:
+                # non-IID classes: gateway 0's devices see the widest
+                # variety (paper Sec. VII-B: "the 1-th gateway ... a wider
+                # variety")
+                q = np.zeros(ncfg.n_devices, dtype=int)
+                for n in range(ncfg.n_devices):
+                    gw = self.net.assign[n]
+                    q[n] = sc.classes if gw == 0 \
+                        else int(self.rng.integers(1, 4))
+                self.ds = make_fl_dataset(ncfg.n_devices, self.d_sizes, q,
+                                          chi=sc.chi, classes=sc.classes,
+                                          seed=sc.seed)
 
         o = cm.flops_vector(self.layers)
         g = cm.mem_vector(self.layers, batch=int(self.d_tilde.max()))
@@ -894,10 +902,10 @@ class Simulation:
         # ``_stats`` (resume fast path) skips the estimation pass entirely —
         # callers providing it are responsible for also restoring the batch
         # RNG state, since no estimation draws are consumed.
-        t0 = time.perf_counter()
-        self.stats = _stats if _stats is not None \
-            else self.engine.estimate_stats(self, params)
-        self.stats_seconds = time.perf_counter() - t0  # for fl_round_bench
+        with obs.span("repro.setup.stats") as stats_span:
+            self.stats = _stats if _stats is not None \
+                else self.engine.estimate_stats(self, params)
+        self.stats_seconds = stats_span.seconds
         self.phi = divergence_bound(self.stats, self.net.assign,
                                     sc.lr, sc.k_iters)
         self.gamma = participation_rates(self.phi, ncfg.n_channels)
@@ -960,18 +968,19 @@ class Simulation:
         seed threaded into stochastic policies — while the scenario-level
         structure (topology, deployment, dataset) stays fixed.
         """
-        if seed is None or seed == self.scenario.seed:
-            self.bs.params = self._init_params
-            self.rng.bit_generator.state = self._rng_state0
-            self.net.rng.bit_generator.state = self._net_rng_state0
-        else:
-            key = jax.random.PRNGKey(seed)
-            _, self.bs.params, _ = model_registry.build_fl_model(
-                self.scenario.model, key, self.scenario)
-            self.rng = np.random.default_rng(seed + 1)
-            self.net.rng = np.random.default_rng(seed)
-        self.run_seed = self.scenario.seed if seed is None else seed
-        self.restart()
+        with obs.span("repro.reset"):
+            if seed is None or seed == self.scenario.seed:
+                self.bs.params = self._init_params
+                self.rng.bit_generator.state = self._rng_state0
+                self.net.rng.bit_generator.state = self._net_rng_state0
+            else:
+                key = jax.random.PRNGKey(seed)
+                _, self.bs.params, _ = model_registry.build_fl_model(
+                    self.scenario.model, key, self.scenario)
+                self.rng = np.random.default_rng(seed + 1)
+                self.net.rng = np.random.default_rng(seed)
+            self.run_seed = self.scenario.seed if seed is None else seed
+            self.restart()
         return self
 
     # -- policies --------------------------------------------------------

@@ -15,11 +15,11 @@ def _seed_numpy():
 class _CompileCounter:
     """Live view over one or more compile-count sources.
 
-    A source is either ``(TRACE_COUNTS_dict, key)`` — the trace-time
-    side-effect counters the repro modules expose (``repro.fl.cohort``,
-    ``repro.fl.shard``, ``repro.core.ddsra_jax``) — or a jitted callable,
-    read through ``_cache_size()``. ``count`` is the number of traces since
-    the counter was entered, summed over all sources.
+    A source is either the name of a ``repro.obs`` counter — the trace-time
+    counters the compiled bodies bump (``trace.cohort.round``,
+    ``trace.shard.train_scan``, ``trace.ddsra.decide``, ...) — or a jitted
+    callable, read through ``_cache_size()``. ``count`` is the number of
+    traces since the counter was entered, summed over all sources.
     """
 
     def __init__(self, sources):
@@ -27,11 +27,11 @@ class _CompileCounter:
         self._start = self._read()
 
     def _read(self) -> int:
+        from repro import obs
         total = 0
         for s in self._sources:
-            if isinstance(s, tuple):
-                d, key = s
-                total += d[key]
+            if isinstance(s, str):
+                total += int(obs.counters.get(s, 0))
             else:
                 total += s._cache_size()
         return total
@@ -47,12 +47,12 @@ def compile_count():
 
     Usage::
 
-        with compile_count((cohort_lib.TRACE_COUNTS, "round")) as c:
+        with compile_count("trace.cohort.round") as c:
             ... run rounds ...
         assert c.count <= 1          # one trace, zero retraces
 
     Pass several sources to count them jointly; pass a jitted function to
-    count via its ``_cache_size()`` instead of a TRACE_COUNTS dict.
+    count via its ``_cache_size()`` instead of a counter name.
     ``c.count`` also reads *inside* the block (it is a live delta).
     """
     @contextlib.contextmanager

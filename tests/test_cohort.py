@@ -105,7 +105,7 @@ def test_cohort_compiles_once_across_varying_subsets(cohort_setup,
     compiled executable (fixed-shape batching contract)."""
     plan, params, ds, d_tilde, gws, gw_onehot = cohort_setup
     rng = np.random.default_rng(0)
-    with compile_count((cohort_lib.TRACE_COUNTS, "round")) as c:
+    with compile_count("trace.cohort.round") as c:
         for trained, l_n in [([0], [1, 2, 3, 0, 0, 0]),
                              ([1], [0, 0, 0, 1, 2, 3]),
                              ([0, 1], [3, 2, 1, 0, 1, 2])]:

@@ -22,9 +22,7 @@ import pytest
 
 import jax
 
-from repro.core import ddsra_jax, policy_sweep
 from repro.core.network import NetworkConfig
-from repro.fl import cohort as cohort_lib
 from repro.fl import fused_sim
 from repro.fl.fused_sim import RoundTelemetry
 from repro.fl.sim import RoundRecord, Scenario, Simulation
@@ -252,10 +250,8 @@ def test_fused_run_is_two_compiles_and_never_retraces(compile_count):
     values everywhere) retraces nothing."""
     sc = _scenario(policy="ddsra_jax")
     Simulation(sc).fused_rounds()                  # warm (or cached)
-    with compile_count((ddsra_jax.TRACE_COUNTS, "decide"),
-                       (ddsra_jax.TRACE_COUNTS, "round"),
-                       (cohort_lib.TRACE_COUNTS, "train_scan"),
-                       (cohort_lib.TRACE_COUNTS, "round")) as c:
+    with compile_count("trace.ddsra.decide", "trace.ddsra.round",
+                       "trace.cohort.train_scan", "trace.cohort.round") as c:
         sim = Simulation(sc)
         sim.reset(seed=123)
         sim.fused_rounds()
@@ -267,7 +263,7 @@ def test_sweep_is_one_compile_across_value_changes(compile_count):
     (same counts) re-runs the same executable."""
     sim = Simulation(_scenario(policy="ddsra_jax"))
     sim.sweep([0.01, 1.0], seeds=[0, 1], rounds=4)           # warm
-    with compile_count((ddsra_jax.TRACE_COUNTS, "sweep")) as c:
+    with compile_count("trace.ddsra.sweep") as c:
         res = sim.sweep([0.5, 50.0], seeds=[3, 9], rounds=4)
     assert c.count == 0
     assert res.taus.shape == (2, 2, 4)
@@ -281,7 +277,7 @@ def test_multi_policy_sweep_is_one_program(compile_count):
     one per policy — and changing values (seeds, V) never retraces."""
     sim = Simulation(_scenario(policy="ddsra_jax"))
     sim.sweep([0.01, 1.0], seeds=[0, 1], rounds=4, policies=_POLICIES)
-    with compile_count((policy_sweep.TRACE_COUNTS, "sweep")) as c:
+    with compile_count("trace.policy_sweep.sweep") as c:
         res = sim.sweep([0.5, 50.0], seeds=[3, 9], rounds=4,
                         policies=_POLICIES)
     assert c.count == 0

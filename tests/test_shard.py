@@ -20,7 +20,6 @@ from repro.core.network import NetworkConfig
 from repro.fl import (CohortLayout, Scenario, Simulation, TieredCohortBatch,
                       make_engine)
 from repro.fl import cohort as cohort_lib
-from repro.fl import shard as shard_lib
 from repro.fl.data import make_fl_dataset, sample_batch, sample_cohort_batch
 from repro.fl.shard import ShardedCohortEngine
 from repro.sharding import COHORT_AXIS, cohort_mesh
@@ -254,7 +253,7 @@ def test_sharded_run_matches_cohort():
 
 def test_sharded_compiles_once_across_rounds(compile_count):
     sc = _scenario(rounds=4, tiers=2)
-    with compile_count((shard_lib.TRACE_COUNTS, "round")) as c:
+    with compile_count("trace.shard.round") as c:
         Simulation(sc_sharded := dataclasses.replace(sc, engine="sharded"))
         Simulation(sc_sharded).run("ddsra")
     assert c.count <= 1
