@@ -174,14 +174,14 @@ def _backward(record: dict) -> None:
 def _kernel_paths(record: dict) -> None:
     """Time the kernels through their real op-layer entry points — compiled
     Pallas on TPU, the Pallas interpreter elsewhere — with whatever blocks
-    the selection table resolves, and tag the rows with both."""
+    the op layer resolves, and tag the rows with both."""
     impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
     interpret = impl == "interpret"
 
     for m, k, n in GEMM_SHAPES:
         x, w, b = _gemm_inputs(m, k, n)
-        blocks = autotune.blocks_for("fused_linear", (m, k, n), "float32",
-                                     interpret=interpret)
+        plan = fused_ops._plan(m, k, n, x.dtype, impl)
+        blocks = (plan.block_m, plan.block_k, plan.block_n)
         fn = jax.jit(lambda a, b_, c: fused_ops.linear(a, b_, c,
                                                        activation="relu",
                                                        impl=impl))

@@ -15,14 +15,16 @@ column-reduction fused in, both applying the relu activation mask inline
 from the saved output so ``dz``/``w.T``/``x.T`` are never materialized in
 HBM (design notes: ``docs/architecture.md``, "The kernel stack"). One
 shared ``kernel.tile_plan`` gates pallas-vs-ref routing for forward and
-both backward contractions. Set ``REPRO_FUSED_LINEAR_IMPL=interpret`` to
+both backward contractions; ``kernel.skinny_plan`` tiles a slot's
+skinny GEMMs from their shape. Set ``REPRO_FUSED_LINEAR_IMPL=interpret`` to
 execute the kernel bodies on CPU (CI does, for tests/test_kernels.py).
 
 ``autotune`` is the cross-cutting module: a block-shape autotuner and a
 persistent per-op selection table (``artifacts/autotune/*.json``, keyed
 ``op|shape|dtype|backend``) that every ops layer consults through
 ``autotune.blocks_for`` — exact autotuned match when one exists, the
-clamped-128 heuristic otherwise; cold keys never sweep. Regenerate with
+clamped-128 heuristic otherwise; cold keys never sweep. ``fused_linear``
+asks ``autotune.exact_blocks`` and falls back to its own rules. Regenerate with
 ``benchmarks/kernel_bench.py --autotune``; validate with
 ``python -m repro.kernels.autotune --check``. The kernels run f32 VMEM
 accumulation for every operand dtype, which is what makes the bf16

@@ -115,6 +115,21 @@ def _valid_blocks(op: str, blocks) -> Optional[Tuple[int, ...]]:
     return None
 
 
+def _exact(op: str, key: str) -> Optional[Tuple[int, ...]]:
+    entry = _entries(op).get(key)
+    return _valid_blocks(op, entry.get("blocks")) if entry else None
+
+
+def exact_blocks(op: str, shape: Sequence[int], dtype: str, *,
+                 interpret: bool = False,
+                 backend: Optional[str] = None) -> Optional[Tuple[int, ...]]:
+    """The blocks of an exact table entry for one call site, or None on a
+    miss (a missing or corrupt table, or a malformed entry, is a miss):
+    for an op layer that has its own rule to fall back on."""
+    return _exact(op, make_key(op, shape, dtype,
+                               backend or backend_id(interpret)))
+
+
 def blocks_for(op: str, shape: Sequence[int], dtype: str, *,
                interpret: bool = False,
                backend: Optional[str] = None) -> Tuple[int, ...]:
@@ -132,10 +147,7 @@ def blocks_for(op: str, shape: Sequence[int], dtype: str, *,
     if key in _LRU:
         _LRU.move_to_end(key)
         return _LRU[key]
-    entry = _entries(op).get(key)
-    blocks = _valid_blocks(op, entry.get("blocks")) if entry else None
-    if blocks is None:
-        blocks = default
+    blocks = _exact(op, key) or default
     _LRU[key] = blocks
     if len(_LRU) > _LRU_MAX:
         _LRU.popitem(last=False)

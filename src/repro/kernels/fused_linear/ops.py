@@ -21,11 +21,15 @@ and never materialize ``w.T``/``x.T`` (nor does the ref path — see
 ``ref.py``). ``REPRO_FUSED_LINEAR_IMPL`` overrides the default impl
 (e.g. ``interpret`` on CPU CI so kernel bodies actually execute).
 
-Block sizes come from the kernel-selection table
-(``repro.kernels.autotune.blocks_for``): an autotuned exact match per
-(shape, dtype, backend) when one exists, the clamped-128 heuristic
-otherwise. The forward GEMM's (M, K, N) triple keys the lookup for all
-three contractions, so the whole VJP tiles from one table entry.
+Block sizes, in order of precedence: an exact entry of the
+kernel-selection table (``repro.kernels.autotune.exact_blocks``, per shape,
+dtype and backend); else ``kernel.skinny_plan`` where it applies (a slot's
+batch of at most ``SKINNY_M_MAX`` rows: one row block, multi-MB weight
+blocks); else the clamped-128 heuristic. The forward GEMM's (M, K, N)
+triple keys the choice for all three contractions, so the whole VJP tiles
+from one plan. Without a table entry the Pallas-or-ref verdict is the
+clamped-128 plan's whichever plan tiles. Each traced call site that runs a
+kernel bumps ``obs.counters["fused_linear.plan.<table|skinny|default>"]``.
 """
 from __future__ import annotations
 
@@ -34,10 +38,12 @@ import os
 
 import jax
 
+from repro import obs
 from repro.kernels import autotune
 from repro.kernels.fused_linear.kernel import (TilePlan, fused_linear,
                                                fused_linear_bwd_dw_db,
-                                               fused_linear_bwd_dx, tile_plan)
+                                               fused_linear_bwd_dx,
+                                               skinny_plan, tile_plan)
 from repro.kernels.fused_linear.ref import (ACTS, fused_linear_bwd_dw_db_ref,
                                             fused_linear_bwd_dx_ref,
                                             fused_linear_ref)
@@ -56,12 +62,22 @@ def _impl_default() -> str:
 
 
 def _plan(m: int, k: int, n: int, dtype, impl: str) -> TilePlan:
-    """Tile plan from the selection table (exact autotuned match or the
-    clamped-128 heuristic), validated by ``tile_plan``'s alignment rule —
-    the single source of block choices for the whole VJP."""
-    bm, bk, bn = autotune.blocks_for("fused_linear", (m, k, n), str(dtype),
-                                     interpret=impl == "interpret")
-    return tile_plan(m, k, n, block_m=bm, block_n=bn, block_k=bk)
+    """Tile plan for the whole VJP: an exact table entry, else the skinny
+    rule where it applies, else the clamped-128 heuristic; ``aligned`` is
+    ``tile_plan``'s verdict on the table's blocks or on the heuristic's.
+    Counts, at trace time, which source tiles a kernel."""
+    blocks = autotune.exact_blocks("fused_linear", (m, k, n), str(dtype),
+                                   interpret=impl == "interpret")
+    source = "default" if blocks is None else "table"
+    bm, bk, bn = blocks or autotune.OPS["fused_linear"][0]
+    plan = tile_plan(m, k, n, block_m=bm, block_n=bn, block_k=bk)
+    if source == "default" and plan.aligned:
+        skinny = skinny_plan(m, k, n, dtype.itemsize)
+        if skinny is not None:
+            source, plan = "skinny", skinny
+    if impl != "ref" and plan.aligned:
+        obs.count(f"fused_linear.plan.{source}")
+    return plan
 
 
 def _kern_kwargs(plan: TilePlan, impl: str) -> dict:

@@ -10,10 +10,14 @@ import pytest
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ops import gqa_attention
 from repro.kernels.flash_attention.ref import attention_ref
+from repro import obs
+from repro.kernels import autotune
+from repro.kernels.fused_linear import kernel as fl_kernel
 from repro.kernels.fused_linear import ops as fused_ops
 from repro.kernels.fused_linear.kernel import (fused_linear,
                                                fused_linear_bwd_dw_db,
-                                               fused_linear_bwd_dx, tile_plan)
+                                               fused_linear_bwd_dx,
+                                               skinny_plan, tile_plan)
 from repro.kernels.fused_linear.ref import (fused_linear_bwd_dw_db_ref,
                                             fused_linear_bwd_dx_ref,
                                             fused_linear_ref)
@@ -318,6 +322,203 @@ def test_tile_plan_clamps_per_dim_and_gates_kernels():
     assert tile_plan(100, 256, 128).aligned     # 100 clamps to one block
     assert tile_plan(127, 127, 127).aligned     # single full-size block
     assert not tile_plan(129, 128, 128).aligned
+
+
+# ---------------------------------------------------------------------------
+# skinny_plan: the shape-derived tiling of a slot's GEMMs
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def no_table(tmp_path, monkeypatch):
+    """An empty kernel-selection table, so ``_plan`` falls to its rules."""
+    monkeypatch.setenv("REPRO_AUTOTUNE_DIR", str(tmp_path))
+    autotune.clear_cache()
+    yield
+    autotune.clear_cache()
+
+
+def _plan_counts() -> dict:
+    return {src: obs.counters[f"fused_linear.plan.{src}"]
+            for src in ("table", "skinny", "default")}
+
+
+def _vmem_limits(m, k, n, plan, itemsize=4) -> dict:
+    """The VMEM limit each kernel passes to Mosaic under ``plan`` (None:
+    the compiler's default scoped limit), read from the traced call."""
+    dt = {4: jnp.float32, 2: jnp.bfloat16}[itemsize]
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, dt)   # noqa: E731
+    kw = dict(block_m=plan.block_m, block_k=plan.block_k,
+              block_n=plan.block_n, interpret=True)
+    calls = {
+        "fwd": (lambda x, w, b: fused_linear(x, w, b, **kw),
+                (sds(m, k), sds(k, n), sds(n))),
+        "dx": (lambda dy, w, y: fused_linear_bwd_dx(dy, w, y, mask="relu",
+                                                    **kw),
+               (sds(m, n), sds(k, n), sds(m, n))),
+        "dw_db": (lambda x, dy, y: fused_linear_bwd_dw_db(x, dy, y,
+                                                          mask="relu", **kw),
+                  (sds(m, k), sds(m, n), sds(m, n))),
+    }
+    out = {}
+    for kern, (fn, args) in calls.items():
+        (eqn,) = [e for e in jax.make_jaxpr(fn)(*args).eqns
+                  if e.primitive.name == "pallas_call"]
+        params = eqn.params["compiler_params"]
+        out[kern] = params["mosaic_tpu"].vmem_limit_bytes if params else None
+    return out
+
+
+# (m, k, n): VGG-11's fc layers at the slot width 95, one row, and a row
+# count above SKINNY_M_MAX
+SKINNY_SHAPES = [(95, 512, 4096), (95, 4096, 4096), (95, 4096, 10),
+                 (1, 4096, 4096), (300, 4096, 4096)]
+
+
+@pytest.mark.parametrize("shape", SKINNY_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_skinny_plan_rule(no_table, shape):
+    m, k, n = shape
+    before = tile_plan(m, k, n)
+    plan = fused_ops._plan(m, k, n, np.dtype(np.float32), "pallas")
+    assert plan.aligned == before.aligned            # same routing verdict
+    if m > fl_kernel.SKINNY_M_MAX:
+        assert skinny_plan(m, k, n, 4) is None
+        assert plan == before                        # today's plan, exactly
+        return
+    assert plan == skinny_plan(m, k, n, 4)
+    assert plan.block_m == m
+    for dim, blk in ((k, plan.block_k), (n, plan.block_n)):
+        assert dim % blk == 0 and (blk % 128 == 0 or blk == dim)
+    # the whole of K first: the row operand is one block, read once
+    assert plan.block_k == k
+    # far fewer grid steps than the clamped-128 plan's
+    steps = (k // plan.block_k) * (n // plan.block_n)
+    assert steps <= 16 < (k // before.block_k) * (n // before.block_n) \
+        or n <= 128
+    limits = _vmem_limits(m, k, n, plan)
+    for kern in fl_kernel.KERNELS:
+        need = fl_kernel.vmem_bytes(kern, m, plan.block_k, plan.block_n, 4)
+        assert need <= fl_kernel.SKINNY_VMEM_BUDGET
+        limit = limits[kern] or fl_kernel._SCOPED_VMEM_DEFAULT
+        assert need <= limit, (kern, need, limit)
+
+
+@pytest.mark.parametrize("shape", SKINNY_SHAPES + [
+    (1000, 4096, 4096),          # the 1,000-row eval forward: ref
+    (127, 128, 129), (129, 128, 128), (96, 300, 128), (8, 200, 64)],
+    ids=lambda s: "x".join(map(str, s)))
+def test_routing_verdict_unchanged_by_skinny_plan(no_table, shape):
+    """Whether a shape runs the kernels or the ref is the clamped-128
+    plan's verdict, whichever plan then tiles it."""
+    m, k, n = shape
+    for dtype in (np.float32, jnp.bfloat16):
+        plan = fused_ops._plan(m, k, n, np.dtype(dtype), "pallas")
+        assert plan.aligned == tile_plan(m, k, n).aligned
+
+
+def test_plan_counter_once_per_traced_call_site(no_table):
+    """Forward, dx and dw+db each count their plan's source once per trace;
+    the ref path counts nothing."""
+    def grad_of(m, k, n, impl):
+        x, w, b = jnp.ones((m, k)), jnp.ones((k, n)), jnp.ones((n,))
+        loss = lambda x, w, b: fused_ops.linear(   # noqa: E731
+            x, w, b, activation="relu", impl=impl).sum()
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, w, b)
+
+    def delta(*args):
+        before = _plan_counts()
+        grad_of(*args)
+        return {s: _plan_counts()[s] - before[s] for s in before}
+
+    assert delta(24, 512, 384, "interpret") == \
+        {"table": 0, "skinny": 3, "default": 0}
+    assert delta(384, 128, 128, "interpret") == \
+        {"table": 0, "skinny": 0, "default": 3}
+    assert delta(24, 512, 384, "ref") == \
+        {"table": 0, "skinny": 0, "default": 0}
+    backend = autotune.backend_id(interpret=True)
+    autotune.record("fused_linear", (24, 512, 384), "float32", backend,
+                    (24, 128, 128), us=1.0, baseline_us=2.0, save=False)
+    assert delta(24, 512, 384, "interpret") == \
+        {"table": 3, "skinny": 0, "default": 0}
+
+
+def _budget_for(m, k, n, bk, bn) -> int:
+    """The VMEM budget under which ``skinny_plan`` picks (bk, bn)."""
+    return max(fl_kernel.vmem_bytes(kern, m, bk, bn, 4)
+               for kern in fl_kernel.KERNELS)
+
+
+# (bk, bn) the rule is steered to at (24, 512, 384): one block, three N
+# blocks with x resident, and K split too (the row operand re-fetched)
+SKINNY_BLOCKS = [(512, 384), (512, 128), (128, 128)]
+
+
+@pytest.mark.parametrize("act", ["relu", "none"])
+@pytest.mark.parametrize("blocks", SKINNY_BLOCKS,
+                         ids=lambda b: "x".join(map(str, b)))
+def test_skinny_plan_vmapped_kernels_match_ref(blocks, act):
+    """Forward, dx and dw+db under the skinny rule's blocks, vmapped over
+    slots as split training runs them, against the references."""
+    slots, (m, k, n) = 3, (24, 512, 384)
+    plan = skinny_plan(m, k, n, 4, budget=_budget_for(m, k, n, *blocks))
+    assert (plan.block_m, plan.block_k, plan.block_n) == (m, *blocks)
+    kw = dict(block_m=plan.block_m, block_k=plan.block_k,
+              block_n=plan.block_n, interpret=True)
+    mask = "relu" if act == "relu" else "none"
+    keys = jax.random.split(jax.random.PRNGKey(_seed((blocks, act))), 4)
+    x = _rand(keys[0], (slots, m, k), jnp.float32)
+    w = _rand(keys[1], (slots, k, n), jnp.float32) / np.sqrt(k)
+    b = _rand(keys[2], (slots, n), jnp.float32)
+    dy = _rand(keys[3], (slots, m, n), jnp.float32)
+
+    y = jax.vmap(lambda x, w, b: fused_linear(x, w, b, activation=act,
+                                              **kw))(x, w, b)
+    y_ref = jax.vmap(lambda x, w, b: fused_linear_ref(x, w, b, act))(x, w, b)
+    np.testing.assert_allclose(y, y_ref, atol=TOL[jnp.float32],
+                               rtol=TOL[jnp.float32])
+    yy = y_ref if mask == "relu" else None
+    dx = jax.vmap(lambda d, w, y: fused_linear_bwd_dx(d, w, y, mask=mask,
+                                                      **kw),
+                  in_axes=(0, 0, None if yy is None else 0))(dy, w, yy)
+    dx_ref = jax.vmap(lambda d, w, y: fused_linear_bwd_dx_ref(d, w, y,
+                                                              mask=mask),
+                      in_axes=(0, 0, None if yy is None else 0))(dy, w, yy)
+    np.testing.assert_allclose(dx, dx_ref, atol=1e-4, rtol=1e-4)
+    dw, db = jax.vmap(
+        lambda x, d, y: fused_linear_bwd_dw_db(x, d, y, mask=mask, **kw),
+        in_axes=(0, 0, None if yy is None else 0))(x, dy, yy)
+    dw_ref, db_ref = jax.vmap(
+        lambda x, d, y: fused_linear_bwd_dw_db_ref(x, d, y, mask=mask),
+        in_axes=(0, 0, None if yy is None else 0))(x, dy, yy)
+    np.testing.assert_allclose(dw, dw_ref, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(db, db_ref, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("act", ["relu", "none", "gelu"])
+def test_skinny_plan_vmapped_grad_matches_ref(no_table, act):
+    """jax.grad of the op, vmapped over slots, tiled by the skinny rule
+    (which the plan counter shows), against jax.grad of the reference."""
+    slots, (m, k, n) = 3, (24, 512, 384)
+    keys = jax.random.split(jax.random.PRNGKey(_seed(("grad", act))), 4)
+    x = _rand(keys[0], (slots, m, k), jnp.float32)
+    w = _rand(keys[1], (slots, k, n), jnp.float32) / np.sqrt(k)
+    b = _rand(keys[2], (slots, n), jnp.float32)
+    ct = _rand(keys[3], (slots, m, n), jnp.float32)
+
+    def slot_grads(linear):
+        def loss(x, w, b, ct):
+            return jnp.vdot(linear(x, w, b), ct)
+        return jax.vmap(jax.grad(loss, argnums=(0, 1, 2)))(x, w, b, ct)
+
+    before = _plan_counts()["skinny"]
+    got = slot_grads(lambda x, w, b: fused_ops.linear(
+        x, w, b, activation=act, impl="interpret"))
+    # forward, dx, dw+db; a smooth activation's backward adds the remat GEMM
+    assert _plan_counts()["skinny"] - before == (3 if act != "gelu" else 4)
+    want = slot_grads(lambda x, w, b: fused_linear_ref(x, w, b, act))
+    for g, r, name in zip(got, want, ("dx", "dw", "db")):
+        np.testing.assert_allclose(g, r, atol=1e-4, rtol=1e-4, err_msg=name)
 
 
 OFF_TILE = [127, 128, 129]

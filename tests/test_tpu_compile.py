@@ -24,6 +24,10 @@ from repro.kernels.ssd_scan import kernel as ssd_kernel
 # VGG-11 at width_mult=1.0 on 32x32 inputs: fc1 512->4096, fc2 4096->4096,
 # fc3 4096->10, at 128 rows per slot batch.
 VGG_FC = [(128, 512, 4096), (128, 4096, 4096), (128, 4096, 10)]
+# The same layers at the benchmark's slot width (95 rows per slot), where
+# the op layer tiles with kernel.skinny_plan: multi-MB blocks whose VMEM
+# limit the kernels raise themselves. A plan that overflows VMEM fails here.
+VGG_FC_SLOT, SLOTS = [(95, 512, 4096), (95, 4096, 4096), (95, 4096, 10)], 6
 
 
 @pytest.fixture(scope="module")
@@ -131,3 +135,29 @@ def test_vmapped_linear_grad_compiles_for_v5e(one_chip, no_compile_cache):
                           _spec(one_chip, (slots, k, n)),
                           _spec(one_chip, (slots, n)))
     _assert_kernel(text, at_least=3)       # forward, dx, dw+db
+
+
+@pytest.mark.parametrize("mkn", VGG_FC_SLOT,
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("which", ["fwd", "grad"])
+def test_vmapped_linear_slot_width_compiles_for_v5e(one_chip,
+                                                     no_compile_cache,
+                                                     which, mkn):
+    m, k, n = mkn
+    assert fl_kernel.skinny_plan(m, k, n, 4) is not None
+
+    def fwd(x, w, b):
+        return jax.vmap(lambda x, w, b: fl_ops.linear(
+            x, w, b, activation="relu", impl="pallas"))(x, w, b)
+
+    def grad(x, w, b):
+        def loss(x, w, b):
+            return fl_ops.linear(x, w, b, activation="relu",
+                                 impl="pallas").sum()
+        return jax.vmap(jax.grad(loss, argnums=(0, 1, 2)))(x, w, b)
+
+    text = _compiled_text(fwd if which == "fwd" else grad,
+                          _spec(one_chip, (SLOTS, m, k)),
+                          _spec(one_chip, (SLOTS, k, n)),
+                          _spec(one_chip, (SLOTS, n)))
+    _assert_kernel(text, at_least=1 if which == "fwd" else 3)
